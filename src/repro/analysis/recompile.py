@@ -1,16 +1,14 @@
 """Recompile guard: count named XLA compiles under ``jax_log_compiles``.
 
 The one-compile invariant says a whole dyn-gated ladder family fills
-through ONE compiled dispatch per (shape, backend).  ``jax.monitoring``
-events (``/jax/core/compile/backend_compile_duration`` etc.) carry no
-function names, so they cannot distinguish the ladder dispatch from the
-tiny eager-op jits (``dynamic_slice``, ``convert_element_type``, ...)
-that fire around it.  Instead we flip ``jax_log_compiles`` on, which
-makes jax's internal loggers emit one ``"Compiling <name> ..."`` record
-per jit-cache miss — *before* the persistent-cache lookup, so a
-lowering is counted even when the XLA binary comes out of
-``.jax_cache``.  That is exactly the event whose count the invariant
-bounds.
+through ONE compiled dispatch per (shape, backend).  The guard flips
+``jax_log_compiles`` on, which makes jax's lowering logger emit one
+``"Compiling jit(<name>) ..."`` record per jit-cache miss — *before*
+the persistent-cache lookup, so a lowering is counted even when the XLA
+binary comes out of the compilation cache.  That is exactly the event
+whose count the invariant bounds; the name tells the ladder dispatch
+apart from the tiny eager-op jits (``dynamic_slice``,
+``convert_element_type``, ...) that fire around it.
 
 This module deliberately imports nothing from ``repro`` so that
 ``sim.runner`` can use it without an import cycle.
@@ -18,18 +16,16 @@ This module deliberately imports nothing from ``repro`` so that
 from __future__ import annotations
 
 import logging
+import re
 from contextlib import contextmanager
 from dataclasses import dataclass, field
 
-# every logger jax routes "Compiling <name>" records through, across the
-# jit / shard_map / pmap paths (version-dependent; harmless if absent)
-_JAX_COMPILE_LOGGERS = (
-    "jax._src.interpreters.pxla",
-    "jax._src.pjit",
-    "jax._src.dispatch",
-)
+# the loggers ``jax_log_compiles`` raises to WARNING: pxla logs one
+# "Compiling jit(<name>) ..." record per jit-cache miss (plain jit and
+# jit(shard_map) alike), dispatch logs the trace/lower/compile timings
+_JAX_COMPILE_LOGGERS = ("jax._src.interpreters.pxla", "jax._src.dispatch")
 
-_PREFIX = "Compiling "
+_COMPILING = re.compile(r"Compiling jit\((?P<name>[^)]*)\) ")
 
 # the name the sharded ladder dispatch compiles under — the inner
 # function built by ``mmu.make_systems_runner`` and wrapped by
@@ -63,11 +59,10 @@ class _Capture(logging.Handler):
         self._on_compile = on_compile
 
     def emit(self, record: logging.LogRecord) -> None:
-        msg = record.getMessage()
-        if msg.startswith(_PREFIX):
-            # "Compiling <name> with global shapes and types ..." /
-            # "Compiling <name> (<id>) for with global shapes ..."
-            name = msg[len(_PREFIX):].split()[0]
+        m = _COMPILING.match(record.getMessage())
+        if m:
+            # "Compiling jit(<name>) with global shapes and types ..."
+            name = m.group("name")
             self._log.names.append(name)
             if self._on_compile is not None:
                 try:

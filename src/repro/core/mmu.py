@@ -22,7 +22,8 @@ Every entry point runs the access loop through one of two BACKENDS
   scan   — the ``jax.lax.scan`` carry loop described above (default);
   pallas — the same step fused into a blocked Pallas kernel
            (``repro.kernels.mmu_step``) that keeps the state carry
-           resident across trace blocks (interpret mode off-TPU).
+           resident across trace blocks.  It runs interpreted on the
+           CPU; on a TPU it is refused up front (``PALLAS_ON_TPU``).
 
 Both are bit-identical (tests/test_mmu_kernel.py); ``time_shards``
 additionally splits the trace time axis into speculative blocks with
@@ -46,23 +47,34 @@ from repro.core.stages import (Dyn, Feats, MMUState, Request, STAGES,
 from repro.core.stages.fold import accum_stats, collect_feats
 
 __all__ = [
-    "BACKENDS", "Dyn", "Feats", "MMUState", "SimConfig", "Stats",
-    "WALK_HIST_BUCKETS", "make_state", "make_step", "make_systems_runner",
-    "resolve_backend", "scan_accesses", "simulate", "simulate_batch",
-    "simulate_systems",
+    "BACKENDS", "PALLAS_ON_TPU", "Dyn", "Feats", "MMUState", "SimConfig",
+    "Stats", "WALK_HIST_BUCKETS", "backend_name", "make_state",
+    "make_step", "make_systems_runner", "resolve_backend",
+    "scan_accesses", "simulate", "simulate_batch", "simulate_systems",
 ]
 
 # access-loop backends: "scan" = lax.scan carry loop, "pallas" = blocked
-# resident-state kernel (repro.kernels.mmu_step; interpret mode off-TPU)
+# resident-state kernel (repro.kernels.mmu_step; interpreted on the CPU)
 BACKENDS = ("scan", "pallas")
 _BACKEND_ENV = "REPRO_SIM_BACKEND"
 
+# why the pallas backend cannot run on a TPU (found by compiling
+# mmu_step.blocked_scan for a described v5e, at tiny and full sizes)
+PALLAS_ON_TPU = (
+    "backend 'pallas' cannot run on a TPU: Mosaic, the TPU Pallas "
+    "compiler, refuses the MMU kernel at every ladder size.  The kernel's "
+    "lax.scan over the trace block is not lowered (scan with per-step "
+    "inputs raises NotImplementedError), and with that replaced by a "
+    "fori_loop the stages' reads of set rows from loaded state lower to "
+    "dynamic_slice, an unimplemented primitive in Pallas TPU lowering.  "
+    "Use backend='scan'.")
 
-def resolve_backend(backend: str | None = None) -> str:
-    """The effective access-loop backend (kwarg > env > "scan").
 
-    Raises ValueError on unknown names so CLI layers can validate BEFORE
-    anything compiles (mirroring the sweep's name/tag validation).
+def backend_name(backend: str | None = None) -> str:
+    """The requested access-loop backend (kwarg > env > "scan").
+
+    Validates the name only and touches no device, so CLI layers can
+    reject a typo BEFORE anything compiles or initializes jax.
     """
     b = backend or os.environ.get(_BACKEND_ENV, "").strip() or "scan"
     if b not in BACKENDS:
@@ -70,6 +82,18 @@ def resolve_backend(backend: str | None = None) -> str:
             f"unknown simulation backend {b!r} (from "
             f"{'backend=' if backend else _BACKEND_ENV}); "
             f"known: {', '.join(BACKENDS)}")
+    return b
+
+
+def resolve_backend(backend: str | None = None) -> str:
+    """The effective access-loop backend for this process's platform.
+
+    ``backend_name`` plus the platform check: "pallas" on a TPU raises
+    up front (``PALLAS_ON_TPU``) instead of falling back to anything.
+    """
+    b = backend_name(backend)
+    if b == "pallas" and jax.default_backend() == "tpu":
+        raise ValueError(PALLAS_ON_TPU)
     return b
 
 

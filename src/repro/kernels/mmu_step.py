@@ -19,11 +19,11 @@ builds for the scan backend, so the two backends are bit-identical by
 construction (pinned by tests/test_mmu_kernel.py on the full native and
 virt ladder families).
 
-TARGET: TPU.  On CPU the kernel runs in interpret mode (the Mosaic
-compiler is unavailable), which preserves bit-identity but not the
-carry-residency speedup — CI uses it as a correctness harness, real
-wall-time wins need a TPU/GPU host.  Block sizes are auto-tuned: see
-``pick_block``.
+On the CPU the kernel runs interpreted, which preserves bit-identity
+but not the carry-residency speedup.  On a TPU it goes through Mosaic,
+which refuses it today (``mmu.PALLAS_ON_TPU`` says why), so
+``mmu.resolve_backend`` rejects the backend there up front.  Block
+sizes are auto-tuned: see ``pick_block``.
 """
 from __future__ import annotations
 
@@ -33,14 +33,22 @@ import os
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 import repro.obs as obs
 
-def _interpret_default() -> bool:
-    # computed lazily, NOT at import time: querying the backend here
-    # would initialize jax before sweep.py's --devices flag can set
-    # --xla_force_host_platform_device_count
-    return jax.default_backend() != "tpu"
+def _interpret() -> bool:
+    """Interpret the kernel on the CPU (the tests); compile it through
+    Mosaic on a TPU.  Asked per call, not at import: querying the
+    backend at import would initialize jax before sweep.py's --devices
+    flag can set --xla_force_host_platform_device_count."""
+    platform = jax.default_backend()
+    if platform not in ("cpu", "tpu"):
+        raise ValueError(
+            f"the pallas MMU kernel runs on a TPU (Mosaic) or interpreted "
+            f"on the CPU, not on {platform!r}; use backend='scan'")
+    return platform == "cpu"
+
 
 # target grid length for auto-tuned blocks: enough blocks that the
 # resident state demonstrably survives grid steps, few enough that
@@ -145,19 +153,10 @@ def _blocked_scan_impl(step, treedefs, block, interpret, n_leaves,
         return pl.BlockSpec((block,) + x.shape[1:],
                             lambda i, _nd=nd: (i,) + (0,) * (_nd - 1))
 
-    kwargs = {}
-    if not interpret:
-        # the grid is a sequential reduction over trace blocks — the
-        # resident-state pattern requires in-order execution
-        try:
-            from jax.experimental.pallas import tpu as pltpu
-            params = getattr(pltpu, "CompilerParams",
-                             getattr(pltpu, "TPUCompilerParams", None))
-            if params is not None:
-                kwargs["compiler_params"] = params(
-                    dimension_semantics=("arbitrary",))
-        except ImportError:  # non-TPU compiled backends pick their own
-            pass
+    # the grid is a sequential reduction over trace blocks — the
+    # resident-state pattern requires in-order execution
+    params = (None if interpret
+              else pltpu.CompilerParams(dimension_semantics=("arbitrary",)))
 
     out = pl.pallas_call(
         kernel,
@@ -168,14 +167,13 @@ def _blocked_scan_impl(step, treedefs, block, interpret, n_leaves,
         out_specs=[_full_spec(x.shape) for x in ins],
         out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype) for x in ins],
         interpret=interpret,
-        **kwargs,
+        compiler_params=params,
     )(*tr_leaves, *ins, *cins)
     return jax.tree.unflatten(
         st_def, [o.reshape(s) for o, s in zip(out, st_shapes)])
 
 
-def blocked_scan(step, st0, trace, consts=None, block: int | None = None,
-                 interpret: bool | None = None):
+def blocked_scan(step, st0, trace, consts=None, block: int | None = None):
     """Scan ``step`` over ``trace`` (time axis 0) in resident-state blocks.
 
     Drop-in for ``lax.scan(step, st0, trace)[0]`` (per-step outputs are
@@ -185,10 +183,11 @@ def blocked_scan(step, st0, trace, consts=None, block: int | None = None,
     ``consts`` is an optional pytree of per-call constants (e.g. the
     ladder's stacked ``Dyn`` scalars) delivered to the kernel as inputs
     — pallas kernels cannot close over traced arrays.  ``block``
-    overrides the auto-tuned trace block size (``pick_block``);
-    ``interpret`` defaults to interpreter mode off-TPU.
+    overrides the auto-tuned trace block size (``pick_block``).  The
+    kernel is interpreted on the CPU and compiled through Mosaic on a
+    TPU, never interpreted there.
     """
-    interpret = _interpret_default() if interpret is None else interpret
+    interpret = _interpret()
 
     # the stage composition bakes config-derived scalars into its
     # closure; a pallas kernel cannot capture constants, so the step is

@@ -22,12 +22,9 @@ import time  # noqa: E402
 import traceback  # noqa: E402
 
 import jax  # noqa: E402
-
-jax.config.update("jax_compilation_cache_dir",
-                  os.environ.get("JAX_CACHE", "/root/repo/.jax_cache"))
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 5)
-
 import jax.numpy as jnp  # noqa: E402
+
+from repro import cachedirs  # noqa: E402
 
 from repro.configs import ARCHS, get_config  # noqa: E402
 from repro.configs.base import SHAPES, cell_status  # noqa: E402
@@ -37,6 +34,8 @@ from repro.launch.mesh import make_production_mesh  # noqa: E402
 from repro.models.model import build  # noqa: E402
 from repro.optim import adamw  # noqa: E402
 from repro.train.train_step import TrainConfig, TrainState, make_train_step  # noqa: E402
+
+cachedirs.enable_compile_cache()
 
 _COLL_RE = re.compile(
     r"(all-gather|all-reduce|reduce-scatter|all-to-all|collective-permute)"
@@ -176,7 +175,7 @@ def main():
     ap.add_argument("--sweep", action="store_true",
                     help="all (arch × shape) cells on this mesh")
     ap.add_argument("--both-meshes", action="store_true")
-    ap.add_argument("--out", default="/root/repo/artifacts/dryrun")
+    ap.add_argument("--out", default="artifacts/dryrun")
     args = ap.parse_args()
 
     cells = []

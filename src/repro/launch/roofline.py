@@ -105,9 +105,9 @@ def analyze(rec: dict) -> dict:
 
 def main():
     ap = argparse.ArgumentParser()
-    ap.add_argument("--dir", default="/root/repo/artifacts/dryrun")
+    ap.add_argument("--dir", default="artifacts/dryrun")
     ap.add_argument("--mesh", default="16x16")
-    ap.add_argument("--out", default="/root/repo/artifacts/roofline.json")
+    ap.add_argument("--out", default="artifacts/roofline.json")
     args = ap.parse_args()
 
     rows = []

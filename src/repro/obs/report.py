@@ -208,7 +208,8 @@ def fill_record(events: list[dict], fill_id: int | None = None,
         elif kind == "count_compiles":
             rec[field] = n_compiles
         elif kind == "derived":
-            rec[field] = rec[arg] <= 1  # one_compile
+            # one_compile: exactly one; zero means the guard saw nothing
+            rec[field] = rec[arg] == 1
         elif kind == "trace_path":
             rec[field] = trace_file
         else:  # pragma: no cover - FIELD_SOURCES is closed by OB001

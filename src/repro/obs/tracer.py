@@ -25,9 +25,9 @@ file; otherwise traces land in ``REPRO_OBS_DIR`` (default
 file itself is only created when the first record is emitted — an
 import alone never touches the filesystem.
 
-This module deliberately imports nothing from ``repro`` (stdlib only),
-so every layer — ``sim.parallel`` included, which otherwise imports no
-repro siblings — can emit into it without a cycle.
+This module imports nothing from ``repro`` but the stdlib-only
+``repro.cachedirs``, so every layer — ``sim.parallel`` included, which
+otherwise imports no repro siblings — can emit into it without a cycle.
 """
 from __future__ import annotations
 
@@ -37,11 +37,12 @@ import os
 import threading
 import time
 
+from repro import cachedirs
+
 SCHEMA = 1  # JSONL record schema (the "meta" header line carries it)
 
 _DEFAULT_DIR = os.path.join(
-    os.path.dirname(os.environ.get("REPRO_SIM_CACHE",
-                                   "/root/repo/.sim_cache")), ".obs_trace")
+    os.path.dirname(cachedirs.sim_cache_dir()), ".obs_trace")
 
 
 def default_path() -> str:

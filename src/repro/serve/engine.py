@@ -198,6 +198,22 @@ def _retire_one(st: EngineState, slot):
     return st, n_tc + n_cl
 
 
+def pages_consistent(st: EngineState) -> jax.Array:
+    """Whether every physical page is exactly one of free or mapped once.
+
+    False when a page is reachable from two block-table entries (an
+    aliased page) or is both mapped and marked free.  Traceable: the
+    load harness evaluates it inside its jitted tick.
+    """
+    rows = st.bt.directory                               # [slots, dir]
+    pages = st.bt.leaves[jnp.maximum(rows, 0)]           # [slots, dir, F]
+    mapped = (rows >= 0)[..., None] & (pages >= 0)
+    n_map = jnp.zeros_like(st.page_free).at[
+        jnp.maximum(pages, 0).reshape(-1)].add(
+        mapped.reshape(-1).astype(st.page_free.dtype))
+    return jnp.all(n_map + st.page_free == 1)
+
+
 def retire(st: EngineState, slot, scope: str | None = None) -> EngineState:
     """Finish a request: shootdown — unmap pages, invalidate translations."""
     st, n_inval = _retire_one(st, slot)

@@ -171,7 +171,9 @@ def run_load(requests: list[Request],
     loop drains in-flight work for at most ``drain_ticks`` extra ticks.
 
     Returns the derived BENCH_serve record (also appended to
-    :data:`SERVE_PERF`).
+    :data:`SERVE_PERF`).  Raises ``RuntimeError`` at the first tick
+    whose state fails ``engine.pages_consistent`` in any lane (a page
+    mapped twice, or both mapped and free).
     """
     cfg = cfg or engine.EngineConfig()
     scope = scope or run
@@ -186,7 +188,7 @@ def run_load(requests: list[Request],
         s, _phys, _src = engine.decode_translate(s, cfg)
         ret = s.slot_live & (targets > 0) & (s.slot_len >= targets)
         s, n_inval = engine.retire_where(s, ret)
-        return s, oks, ret, n_inval
+        return s, oks, ret, n_inval, engine.pages_consistent(s)
 
     step = parallel.shard_lanes(jax.vmap(lane_step), lanes)
 
@@ -234,14 +236,19 @@ def run_load(requests: list[Request],
 
             with obs.span(names.SPAN_DECODE_STEP):
                 t0 = time.perf_counter()
-                st, oks, rets, n_inval = step(
+                st, oks, rets, n_inval, pages_ok = step(
                     st, jnp.asarray(admit_blocks), jnp.asarray(targets_h))
                 jax.block_until_ready(st)
                 obs.observe(engine.scoped(names.HIST_DECODE_STEP_S, scope),
                             time.perf_counter() - t0)
             obs.REGISTRY.inc(engine.scoped(names.CTR_DECODE_STEPS, scope))
 
-            oks_h = np.asarray(jax.device_get(oks))
+            oks_h, pages_ok_h = jax.device_get((oks, pages_ok))
+            if not np.all(pages_ok_h):
+                raise RuntimeError(
+                    f"tick {t}: a KV page is mapped twice, or mapped and "
+                    f"free at once")
+            oks_h = np.asarray(oks_h)
             rets_h = np.asarray(jax.device_get(rets))
             n_adm = n_rej = n_ret = 0
             for ln in range(lanes):

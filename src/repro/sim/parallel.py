@@ -41,11 +41,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 import repro.obs as obs
 
-try:  # jax >= 0.6 promotes shard_map out of experimental
-    from jax import shard_map  # type: ignore[attr-defined]
-except ImportError:
-    from jax.experimental.shard_map import shard_map
-
 AXIS_SYS = "sys"
 AXIS_WL = "wl"
 AXIS_CORE = "core"
@@ -53,7 +48,7 @@ AXIS_T = "t"
 AXIS_LANE = "lane"
 
 __all__ = ["AXIS_SYS", "AXIS_WL", "AXIS_CORE", "AXIS_T", "AXIS_LANE",
-           "MeshPlan", "plan_mesh", "build_mesh", "shard_wrap",
+           "MeshPlan", "plan_mesh", "build_mesh", "shard_jit", "shard_wrap",
            "shard_systems", "pick_t_shards", "time_shard_scan",
            "plan_lane_dim", "shard_lanes"]
 
@@ -170,6 +165,33 @@ def _pad_sys(x: jax.Array, pad: int) -> jax.Array:
         [x, jnp.broadcast_to(x[-1:], (pad,) + x.shape[1:])])
 
 
+def _specs(plan: MeshPlan):
+    """(trace spec, output spec) of the plan's mesh."""
+    if plan.core_dim > 1:
+        # multicore 3-D mesh: trace leaves are [T, W, C] and every
+        # output leaf leads with [S_blk, W_blk, C_blk]
+        return P(None, AXIS_WL, AXIS_CORE), P(AXIS_SYS, AXIS_WL, AXIS_CORE)
+    # single-core (or inner-vmap core lanes): a trailing core axis, if
+    # any, stays replicated
+    return P(None, AXIS_WL), P(AXIS_SYS, AXIS_WL)
+
+
+def shard_jit(fn, plan: MeshPlan, mesh: Mesh):
+    """``jit(shard_map(fn))`` over ``mesh`` with the plan's specs.
+
+    ``check_vma=False``: the body carries no collectives, and its
+    initial scan carry is built inside the body
+    (``mmu.make_systems_runner``), so it is not typed as varying over
+    the mesh axes; the lanes never mix, so there is nothing to verify.
+    Takes the mesh so that a compile for a described (not attached)
+    device can build the same program.
+    """
+    trace_spec, out_spec = _specs(plan)
+    return jax.jit(jax.shard_map(
+        fn, mesh=mesh, in_specs=(P(AXIS_SYS), trace_spec),
+        out_specs=out_spec, check_vma=False))
+
+
 def shard_wrap(fn, plan: MeshPlan):
     """Wrap ``fn`` for the mesh ONCE; returns ``call(dyns, traces)``.
 
@@ -177,31 +199,15 @@ def shard_wrap(fn, plan: MeshPlan):
     and trace leaves ``[T, W_blk, ...]``; every output leaf must lead
     with ``[S_blk, W_blk]``.  The system axis is padded to the mesh (see
     ``plan_mesh``) and sliced back before returning, so callers always
-    see exactly [S, W] outputs.  ``check_rep=False`` where the jax
-    version still takes it: the body carries no collectives, so there
-    are no replication claims to verify.
+    see exactly [S, W] outputs.
 
     The shard_map + jit wrapper is built here, outside the returned
     closure: same-shape calls (``run_ladder``'s fixed-width chunks) hit
     one jit cache entry and trace/lower exactly once.
     """
     mesh = build_mesh(plan)
-    if plan.core_dim > 1:
-        # multicore 3-D mesh: trace leaves are [T, W, C] and every
-        # output leaf leads with [S_blk, W_blk, C_blk]
-        trace_spec = P(None, AXIS_WL, AXIS_CORE)
-        out_spec = P(AXIS_SYS, AXIS_WL, AXIS_CORE)
-    else:
-        # single-core (or inner-vmap core lanes): the exact 2-D specs
-        # of before; a trailing core axis, if any, stays replicated
-        trace_spec = P(None, AXIS_WL)
-        out_spec = P(AXIS_SYS, AXIS_WL)
-    specs = dict(in_specs=(P(AXIS_SYS), trace_spec), out_specs=out_spec)
-    try:
-        sharded = shard_map(fn, mesh=mesh, check_rep=False, **specs)
-    except TypeError:  # newer jax dropped/renamed check_rep
-        sharded = shard_map(fn, mesh=mesh, **specs)
-    jitted = jax.jit(sharded)
+    trace_spec, _ = _specs(plan)
+    jitted = shard_jit(fn, plan, mesh)
 
     def call(dyns, traces):
         S = jax.tree.leaves(dyns)[0].shape[0]
@@ -372,12 +378,9 @@ def shard_lanes(fn, n_lanes: int, n_devices: int | None = None):
     dim = plan_lane_dim(n_lanes, n_devices)
     mesh = Mesh(np.asarray(jax.devices()[:dim]), (AXIS_LANE,))
     spec = P(AXIS_LANE)
-    try:
-        sharded = shard_map(fn, mesh=mesh, in_specs=spec, out_specs=spec,
-                            check_rep=False)
-    except TypeError:  # newer jax dropped/renamed check_rep
-        sharded = shard_map(fn, mesh=mesh, in_specs=spec, out_specs=spec)
-    jitted = jax.jit(sharded)
+    # no collectives in the per-lane step (see shard_jit)
+    jitted = jax.jit(jax.shard_map(fn, mesh=mesh, in_specs=spec,
+                                   out_specs=spec, check_vma=False))
     sharding = NamedSharding(mesh, spec)
 
     def call(*args):

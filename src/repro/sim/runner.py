@@ -22,26 +22,21 @@ import tempfile
 from concurrent.futures import ThreadPoolExecutor
 
 import jax
+import jax.numpy as jnp
+import numpy as np
 
-# persistent XLA compile cache: sim step graphs take minutes to compile
-# on this 1-core container; compile once across processes.
-jax.config.update("jax_compilation_cache_dir",
-                  os.environ.get("JAX_CACHE", "/root/repo/.jax_cache"))
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 5)
+import repro.obs as obs
+from repro import cachedirs
+from repro.analysis import recompile
+from repro.core import mmu
+from repro.core.mmu import make_systems_runner, simulate, simulate_batch
+from repro.kernels import mmu_step
+from repro.obs import jaxprof
+from repro.sim import parallel, systems, trace_gen
 
-import jax.numpy as jnp  # noqa: E402
-import numpy as np  # noqa: E402
+cachedirs.enable_compile_cache()
 
-import repro.obs as obs  # noqa: E402
-from repro.analysis import recompile  # noqa: E402
-from repro.core import mmu  # noqa: E402
-from repro.core.mmu import (  # noqa: E402
-    make_systems_runner, simulate, simulate_batch)
-from repro.kernels import mmu_step  # noqa: E402
-from repro.obs import jaxprof  # noqa: E402
-from repro.sim import parallel, systems, trace_gen  # noqa: E402
-
-CACHE_DIR = os.environ.get("REPRO_SIM_CACHE", "/root/repo/.sim_cache")
+CACHE_DIR = cachedirs.sim_cache_dir()
 
 # ladder dispatch width: workloads per compiled simulate call.  The last
 # chunk pads by repeating its final workload, so EVERY run_ladder call —

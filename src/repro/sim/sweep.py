@@ -33,10 +33,11 @@ families (single-core ladders keep their default workload list):
     python -m repro.sim.sweep --cores 4 --mix bc+rnd+xs --mix dlrm+gen
 
 Backend selection: ``--backend {scan,pallas}`` picks the access-loop
-implementation (bit-identical results; pallas runs in interpreter mode
-off-TPU) and ``--time-shards N`` splits each trace's time axis into N
-speculative blocks resolved to the exact serial carry — it needs a 1x1
-("sys", "wl") mesh, so it conflicts with ``--mesh`` unless that is 1x1.
+implementation (bit-identical results; pallas runs interpreted on the
+CPU and is refused on a TPU, see ``mmu.PALLAS_ON_TPU``) and
+``--time-shards N`` splits each trace's time axis into N speculative
+blocks resolved to the exact serial carry — it needs a 1x1 ("sys",
+"wl") mesh, so it conflicts with ``--mesh`` unless that is 1x1.
 
 Observability: ``--obs-trace PATH`` points the process-global obs
 tracer at PATH, so every ladder fill's span tree lands in that JSONL
@@ -136,7 +137,9 @@ def parse_args(args):
     def _backend(val, flag):
         val = _value(val, flag, "a backend name")
         try:
-            return mmu.resolve_backend(val)
+            # the name only: the platform check initializes jax, which
+            # must wait until main has applied --devices
+            return mmu.backend_name(val)
         except ValueError as e:
             raise SystemExit(str(e)) from None
 

@@ -4,11 +4,9 @@ import os
 # strictly dryrun.py-local (assignment requirement).
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
-import jax  # noqa: E402  (env vars above must be set before jax imports)
+from repro import cachedirs  # noqa: E402  (env vars above come first)
 
-jax.config.update("jax_compilation_cache_dir",
-                  os.environ.get("JAX_CACHE", "/root/repo/.jax_cache"))
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 5)
+cachedirs.enable_compile_cache()
 
 
 def pytest_configure(config):

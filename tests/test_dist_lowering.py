@@ -15,6 +15,7 @@ import pytest
 # tests resume automatically once a PR adds repro.dist
 pytest.importorskip("repro.dist", reason="repro.dist not in tree")
 
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 ENV = dict(os.environ,
            XLA_FLAGS="--xla_force_host_platform_device_count=8",
            PYTHONPATH="src",
@@ -24,7 +25,7 @@ ENV = dict(os.environ,
 def _run(code: str):
     r = subprocess.run([sys.executable, "-c", textwrap.dedent(code)],
                        capture_output=True, text=True, env=ENV,
-                       cwd="/root/repo", timeout=900)
+                       cwd=REPO, timeout=900)
     assert r.returncode == 0, r.stdout + r.stderr
     return r.stdout
 

@@ -110,6 +110,29 @@ def test_resolve_backend_validates(monkeypatch):
         mmu.resolve_backend()
 
 
+def test_pallas_is_refused_up_front_on_a_tpu(monkeypatch):
+    """On a TPU the pallas backend raises with Mosaic's reason instead of
+    running interpreted or falling back to scan; naming it stays a pure
+    name check that touches no device."""
+    monkeypatch.delenv("REPRO_SIM_BACKEND", raising=False)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(ValueError) as e:
+        mmu.resolve_backend("pallas")
+    assert str(e.value) == mmu.PALLAS_ON_TPU
+    assert mmu.resolve_backend("scan") == "scan"
+    assert mmu.backend_name("pallas") == "pallas"
+    with pytest.raises(ValueError, match="unknown simulation backend"):
+        mmu.backend_name("fast")
+
+
+def test_blocked_scan_runs_only_on_cpu_or_tpu(monkeypatch):
+    monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+    tr = jnp.arange(8, dtype=jnp.int32)
+    st0 = (jnp.zeros((5,), jnp.int32), jnp.int32(0))
+    with pytest.raises(ValueError, match="not on 'gpu'"):
+        mmu_step.blocked_scan(_toy_step, st0, tr)
+
+
 def test_sweep_cli_rejects_bad_backend_and_time_shards():
     """A typo'd --backend must die at parse time, BEFORE any ladder
     compile (mirroring the --tags fix)."""

@@ -76,6 +76,27 @@ def test_admit_rejects_on_pool_exhaustion_without_aliasing():
     assert not bool(ok3)
 
 
+def test_pages_consistent_flags_aliased_and_free_mapped_pages():
+    """The per-tick device invariant run_load checks agrees with the
+    host reference on a clean state and catches both faults."""
+    cfg = engine.EngineConfig(n_slots=4, max_blocks_per_req=8,
+                              n_pool_pages=8, n_leaf_rows=16,
+                              tc_sets=8, tc_ways=2, n_clusters=16)
+    st = engine.init(cfg)
+    assert bool(engine.pages_consistent(st))
+    st, ok = engine.admit(st, 0, 3)
+    assert bool(ok)
+    _assert_no_aliasing(st)
+    assert bool(engine.pages_consistent(st))
+    row = int(st.bt.directory[0, 0])
+    page = int(st.bt.leaves[row, 0])
+    aliased = st._replace(bt=st.bt._replace(
+        leaves=st.bt.leaves.at[row, 3].set(page)))
+    assert not bool(engine.pages_consistent(aliased))
+    mapped_and_free = st._replace(page_free=st.page_free.at[page].set(1))
+    assert not bool(engine.pages_consistent(mapped_and_free))
+
+
 def test_decode_grow_stalls_when_pool_exhausted():
     cfg = engine.EngineConfig(n_slots=2, max_blocks_per_req=8,
                               n_pool_pages=4, n_leaf_rows=16,
