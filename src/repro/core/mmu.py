@@ -36,6 +36,7 @@ import os
 import jax
 import jax.numpy as jnp
 
+import repro.obs as obs
 # a pure pytree/mesh utility with no repro.core (or sim-sibling) imports,
 # so this core module can use it without a layering cycle
 from repro.sim import parallel
@@ -162,7 +163,8 @@ def make_step(cfg: SimConfig, stage_names=None, dyn: Dyn | None = None):
         trans = jnp.int32(0)   # cycles up to and including the L2 TLB
         past_l2 = jnp.int32(0)  # cycles past the L2 TLB (Fig 9/22/29)
         for stg in stages:
-            st, res = stg.lookup(cfg, st, req, need)
+            with obs.scope(f"{stg.name}.lookup"):
+                st, res = stg.lookup(cfg, st, req, need)
             need = need & ~res.hit
             out[stg.name] = res._replace(need=need)
             if stg.past_l2:
@@ -173,7 +175,8 @@ def make_step(cfg: SimConfig, stage_names=None, dyn: Dyn | None = None):
 
         # ---------------- fill pass: refills, learning, background walks
         for stg in fills:
-            st = stg.fill(cfg, st, req, out)
+            with obs.scope(f"{stg.name}.fill"):
+                st = stg.fill(cfg, st, req, out)
 
         # shared-tier port contention (multicore only): accesses that
         # went past the private L2 TLB contend for the shared L3/POM/
@@ -189,14 +192,16 @@ def make_step(cfg: SimConfig, stage_names=None, dyn: Dyn | None = None):
         trans = trans + past_l2
 
         # ---------------- the data access itself
-        hier, dcyc = access_data(st.hier, req.line, now, pressure,
-                                 cfg.tlb_aware, cfg.lat, geom, dramc)
+        with obs.scope(obs.names.SCOPE_DATA):
+            hier, dcyc = access_data(st.hier, req.line, now, pressure,
+                                     cfg.tlb_aware, cfg.lat, geom, dramc)
         st = st._replace(hier=hier)
 
-        st = st._replace(stats=accum_stats(s0, st, out, walk_res,
-                                           trans, past_l2, dcyc))
-        if cfg.collect:
-            st = collect_feats(cfg, st, req, out, walk_res)
+        with obs.scope(obs.names.SCOPE_STATS):
+            st = st._replace(stats=accum_stats(s0, st, out, walk_res,
+                                               trans, past_l2, dcyc))
+            if cfg.collect:
+                st = collect_feats(cfg, st, req, out, walk_res)
         return st, ()
 
     return step
@@ -238,17 +243,21 @@ def _shared_tier_extras(cfg) -> bool:
 
 def _extras_of(cfg, l2a, l2m, hd, ht, feats, pc4, shared=None,
                index=lambda x: x):
-    e = {"l2_access": int(index(l2a)), "l2_miss": int(index(l2m)),
-         "hist_reuse_data": jax.device_get(index(hd)),
-         "hist_reuse_tlb": jax.device_get(index(ht))}
+    """One lane's extras; every value is one ``device_get`` of
+    ``index(x)`` (a device read where ``x`` is a device array)."""
+    def get(x):
+        return jax.device_get(index(x))
+
+    e = {"l2_access": int(get(l2a)), "l2_miss": int(get(l2m)),
+         "hist_reuse_data": get(hd), "hist_reuse_tlb": get(ht)}
     if shared is not None and _shared_tier_extras(cfg):
-        e["l3_access"] = int(index(shared[0]))
-        e["l3_trans"] = int(index(shared[1]))
-        e["dramc_access"] = int(index(shared[2]))
-        e["dramc_hit"] = int(index(shared[3]))
+        e["l3_access"] = int(get(shared[0]))
+        e["l3_trans"] = int(get(shared[1]))
+        e["dramc_access"] = int(get(shared[2]))
+        e["dramc_hit"] = int(get(shared[3]))
     if cfg.collect:
-        e["feats"] = jax.tree.map(lambda x: jax.device_get(index(x)), feats)
-        e["pc4"] = jax.tree.map(lambda x: jax.device_get(index(x)), pc4)
+        e["feats"] = jax.tree.map(get, feats)
+        e["pc4"] = jax.tree.map(get, pc4)
     return e
 
 
@@ -380,7 +389,8 @@ def make_systems_runner(cfg: SimConfig, plan, stage_names=None,
                 lambda x: x.reshape((x.shape[0], w_blk * c_blk)
                                     + x.shape[3:]), tr)
         lanes = w_blk if c_blk is None else w_blk * c_blk
-        st0 = _broadcast_state(cfg, (lanes,))
+        with obs.scope(obs.names.SCOPE_INIT):
+            st0 = _broadcast_state(cfg, (lanes,))
 
         def unflatten(outs):
             if c_blk is None:
@@ -395,16 +405,19 @@ def make_systems_runner(cfg: SimConfig, plan, stage_names=None,
                 st, _ = jax.lax.scan(
                     lambda ss, acc: (jax.vmap(step)(ss, acc)[0], ()),
                     st0, tr)
-                return _finalize(st, batch_dims=1)
+                with obs.scope(obs.names.SCOPE_FINALIZE):
+                    return _finalize(st, batch_dims=1)
 
             return unflatten(jax.vmap(one_system)(d))
         # pallas: the system vmap moves inside the kernel's inner scan
         # (see _step_sw) so the pallas_call itself is never vmapped
         s_blk = jax.tree.leaves(d)[0].shape[0]
-        st = scan_accesses(_step_sw(cfg, stage_names),
-                           _broadcast_state(cfg, (s_blk, lanes)), tr,
+        with obs.scope(obs.names.SCOPE_INIT):
+            st0 = _broadcast_state(cfg, (s_blk, lanes))
+        st = scan_accesses(_step_sw(cfg, stage_names), st0, tr,
                            backend=backend, consts=d, block=block)
-        return unflatten(_finalize(st, batch_dims=2))
+        with obs.scope(obs.names.SCOPE_FINALIZE):
+            return unflatten(_finalize(st, batch_dims=2))
 
     if t_shards <= 1:
         dispatch = parallel.shard_wrap(run_systems, plan)
@@ -443,24 +456,36 @@ def make_systems_runner(cfg: SimConfig, plan, stage_names=None,
         leaf = jax.tree.leaves(traces)[0]
         W = leaf.shape[1]
         C = leaf.shape[2] if leaf.ndim >= 3 else None
-        stats, l2a, l2m, hd, ht, feats, pc4, shared = dispatch(dyns,
-                                                               traces)
-        stats = jax.tree.map(jax.device_get, stats)
-        if C is None:
-            per = [[jax.tree.map(lambda x, s=s, w=w: x[s, w], stats)
-                    for w in range(W)] for s in range(S)]
-            extras = [[_extras_of(cfg, l2a, l2m, hd, ht, feats, pc4,
-                                  shared,
-                                  index=lambda x, s=s, w=w: x[s, w])
-                       for w in range(W)] for s in range(S)]
-            return per, extras
-        # multicore: per[s][w] / extras[s][w] are per-core lists
-        per = [[[jax.tree.map(lambda x, s=s, w=w, k=k: x[s, w, k], stats)
-                 for k in range(C)] for w in range(W)] for s in range(S)]
-        extras = [[[_extras_of(cfg, l2a, l2m, hd, ht, feats, pc4, shared,
-                               index=lambda x, s=s, w=w, k=k: x[s, w, k])
+        with obs.span(obs.names.SPAN_SIM_LAUNCH):
+            outs = dispatch(dyns, traces)
+        stats, l2a, l2m, hd, ht, feats, pc4, shared = outs
+        with obs.span(obs.names.SPAN_SIM_FETCH):
+            stats = jax.tree.map(jax.device_get, stats)
+        with obs.span(obs.names.SPAN_SIM_UNPACK) as unpack:
+            reads = [0]
+
+            def extras_at(*ix):
+                def index(x):  # each index is then read from the device
+                    reads[0] += 1
+                    return x[ix]
+                return _extras_of(cfg, l2a, l2m, hd, ht, feats, pc4,
+                                  shared, index=index)
+
+            if C is None:
+                per = [[jax.tree.map(lambda x, s=s, w=w: x[s, w], stats)
+                        for w in range(W)] for s in range(S)]
+                extras = [[extras_at(s, w) for w in range(W)]
+                          for s in range(S)]
+            else:
+                # multicore: per[s][w] / extras[s][w] are per-core lists
+                per = [[[jax.tree.map(
+                    lambda x, s=s, w=w, k=k: x[s, w, k], stats)
                     for k in range(C)] for w in range(W)]
-                  for s in range(S)]
+                    for s in range(S)]
+                extras = [[[extras_at(s, w, k) for k in range(C)]
+                           for w in range(W)] for s in range(S)]
+            obs.count(obs.names.CTR_UNPACK_READS, reads[0])
+            unpack.set(reads=reads[0])
         return per, extras
 
     run.last_time_shard_info = None

@@ -18,10 +18,19 @@ Work on other threads passes ``parent=fill`` explicitly (thread-local
 implicit nesting does not cross threads).  ``python -m repro.obs
 report`` reconstructs BENCH records from the JSONL — see ``report``.
 
-Stdlib-only at import time (jax only inside ``jaxprof`` helpers), so
-any layer — ``sim.parallel`` included — may import it without cycles.
+Code traced by JAX names its parts with :func:`scope`, which puts a
+declared name into the HLO ``op_name`` metadata and counts the part's
+trace seconds.
+
+Stdlib-only at import time (jax only inside :func:`scope`, the
+``jaxprof`` helpers and the tracer's profiler annotations, which it
+opens only once jax is imported), so any layer — ``sim.parallel``
+included — may import it without cycles.
 """
 from __future__ import annotations
+
+import contextlib
+import time
 
 from repro.obs import jaxprof, names, report  # noqa: F401  (re-export)
 from repro.obs.registry import Registry
@@ -67,6 +76,24 @@ def current() -> Span | None:
 
 def overhead_s() -> float:
     return tracer().overhead_s
+
+
+@contextlib.contextmanager
+def scope(name: str):
+    """``jax.named_scope(name)`` around code that JAX traces, so every op
+    traced inside carries ``name`` in its HLO ``op_name``; the block's
+    host time adds to counter ``sim.trace_s.<name>``.  The body runs
+    only while JAX traces it, so the counter holds trace time alone.
+    ``name`` must be one of ``names.SCOPE_NAMES``."""
+    if name not in names.SCOPE_NAMES:
+        raise ValueError(f"scope {name!r} is not declared in "
+                         f"repro.obs.names.SCOPE_NAMES")
+    import jax
+
+    t0 = time.perf_counter()
+    with jax.named_scope(name):
+        yield
+    REGISTRY.inc(names.TRACE_S_PREFIX + name, time.perf_counter() - t0)
 
 
 # ---------------------------------------------------- metrics facade

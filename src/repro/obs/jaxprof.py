@@ -1,12 +1,6 @@
-"""Optional jax.profiler passthrough + device-memory events.
+"""Device-memory events.
 
-The span trace answers "where did the wall time go" at pipeline
-granularity; for kernel-level truth on the TPU phase-2 runs you want
-jax's own profiler.  :func:`maybe_profile` wraps a block in
-``jax.profiler.trace(logdir)`` when a log dir is configured
-(``REPRO_OBS_JAXPROF`` or an explicit argument) and is a no-op
-otherwise — the sweep pipeline calls it unconditionally.
-:func:`device_memory_event` snapshots ``Device.memory_stats()`` into an
+:func:`device_memory_event` snapshots ``Device.memory_stats()`` into a
 ``device_memory`` trace event where the backend exposes it (TPU/GPU;
 CPU returns None and emits nothing).
 
@@ -14,26 +8,6 @@ jax imports are deferred so ``repro.obs`` stays importable — and its
 CLI usable on raw JSONL files — without initializing jax.
 """
 from __future__ import annotations
-
-import os
-from contextlib import contextmanager
-
-
-def profiler_logdir(logdir: str | None = None) -> str | None:
-    return logdir or os.environ.get("REPRO_OBS_JAXPROF", "").strip() or None
-
-
-@contextmanager
-def maybe_profile(logdir: str | None = None):
-    """``jax.profiler.trace(logdir)`` when configured, else a no-op."""
-    logdir = profiler_logdir(logdir)
-    if not logdir:
-        yield None
-        return
-    import jax
-
-    with jax.profiler.trace(logdir):
-        yield logdir
 
 
 def device_memory_event(emit, parent=None):

@@ -32,6 +32,17 @@ Span taxonomy (docs/architecture.md, "Observability"):
   └─ device_memory            (event) live device-memory stats where the
                               backend exposes them (TPU phase-2 runs)
 
+  sim.launch                  one ``mmu.make_systems_runner`` call: the
+                              call into the jitted program until it
+                              returns (an asynchronous launch); a child
+                              of whatever span holds the call
+                              (``run_ladder``'s ``dispatch``)
+  sim.fetch                   its ``device_get`` of the Stats leaves (waits
+                              for the device to finish)
+  sim.unpack                  its per-lane ``per``/``extras`` building;
+                              attr ``reads``: the device-array reads made
+                              (also counter ``sim.unpack_reads``)
+
   serve.decode_step           one timed serving decode tick
                               (``serve.engine.decode_step``)
 
@@ -42,6 +53,20 @@ Span taxonomy (docs/architecture.md, "Observability"):
                               BENCH_serve field re-derives from the
                               run's subtree alone (OB001, schema-5
                               discipline)
+
+Every span also opens a ``jax.profiler.TraceAnnotation`` of its name
+while a profiler records (``tracer.Span``), so the spans land in a
+profiler trace on the profiler's clock, beside the device's events.
+
+Scopes (``obs.scope``): ``jax.named_scope`` names inside the access
+scan, carried into the HLO ``op_name`` metadata of every op they trace,
+so a profiler trace's op time can be summed by stage.  One
+``<stage>.lookup`` and ``<stage>.fill`` per translation stage
+(``STAGE_SCOPES``), ``data`` (the data-cache access), ``stats``
+(``accum_stats`` and ``collect_feats``), ``init`` (the state broadcast)
+and ``finalize`` (``mmu._finalize``).  Each scope's host time while JAX
+traces it accumulates in registry counter ``sim.trace_s.<scope>``
+(in memory only; nothing runs once the program is compiled).
 """
 from __future__ import annotations
 
@@ -52,9 +77,27 @@ SPAN_CHUNK_WAIT = "chunk_wait"
 SPAN_DISPATCH = "dispatch"
 SPAN_DECODE_STEP = "serve.decode_step"
 SPAN_SERVE_RUN = "serve.load_run"
+SPAN_SIM_LAUNCH = "sim.launch"
+SPAN_SIM_FETCH = "sim.fetch"
+SPAN_SIM_UNPACK = "sim.unpack"
 
 SPAN_NAMES = (SPAN_LADDER_FILL, SPAN_TRACE_GEN, SPAN_CHUNK_WAIT,
-              SPAN_DISPATCH, SPAN_DECODE_STEP, SPAN_SERVE_RUN)
+              SPAN_DISPATCH, SPAN_DECODE_STEP, SPAN_SERVE_RUN,
+              SPAN_SIM_LAUNCH, SPAN_SIM_FETCH, SPAN_SIM_UNPACK)
+
+# ------------------------------------------------------------ scopes
+# the translation stages of repro.core.stages.STAGES
+STAGES = ("l1_tlb", "l2_tlb", "victima", "l3_tlb", "pom", "restseg",
+          "rev", "ptw", "ptw2d")
+STAGE_SCOPES = tuple(f"{s}.{phase}" for s in STAGES
+                     for phase in ("lookup", "fill"))
+SCOPE_DATA = "data"
+SCOPE_STATS = "stats"
+SCOPE_INIT = "init"
+SCOPE_FINALIZE = "finalize"
+
+SCOPE_NAMES = STAGE_SCOPES + (SCOPE_DATA, SCOPE_STATS, SCOPE_INIT,
+                              SCOPE_FINALIZE)
 
 # ------------------------------------------------------------ events
 EV_COMPILE = "xla_compile"
@@ -77,6 +120,9 @@ CTR_DECODE_STEPS = "serve.decode_steps"
 CTR_REQS_ADMITTED = "serve.admitted"
 CTR_REQS_RETIRED = "serve.retired"
 CTR_POOL_EXHAUSTED = "serve.pool_exhausted"
+CTR_UNPACK_READS = "sim.unpack_reads"
+TRACE_S_PREFIX = "sim.trace_s."  # + a scope name: its trace seconds
+CTR_TRACE_S = tuple(TRACE_S_PREFIX + s for s in SCOPE_NAMES)
 
 GAUGE_PAGES_FREE = "serve.pages_free"
 GAUGE_SLOT_OCCUPANCY = "serve.slot_occupancy"
@@ -87,6 +133,7 @@ HIST_REQ_TICKS = "serve.req_ticks"
 COUNTER_NAMES = (CTR_SIM_CACHE_HIT, CTR_SIM_CACHE_MISS,
                  CTR_SIM_CACHE_STORE, CTR_VTC_HIT_TC, CTR_VTC_HIT_CLUSTER,
                  CTR_VTC_WALK, CTR_VTC_INVALIDATE, CTR_DECODE_STEPS,
-                 CTR_REQS_ADMITTED, CTR_REQS_RETIRED, CTR_POOL_EXHAUSTED)
+                 CTR_REQS_ADMITTED, CTR_REQS_RETIRED, CTR_POOL_EXHAUSTED,
+                 CTR_UNPACK_READS) + CTR_TRACE_S
 GAUGE_NAMES = (GAUGE_PAGES_FREE, GAUGE_SLOT_OCCUPANCY)
 HIST_NAMES = (HIST_DECODE_STEP_S, HIST_REQ_TICKS)

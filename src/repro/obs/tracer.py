@@ -25,6 +25,12 @@ file; otherwise traces land in ``REPRO_OBS_DIR`` (default
 file itself is only created when the first record is emitted — an
 import alone never touches the filesystem.
 
+While a JAX profiler records, every span is also a
+``jax.profiler.TraceAnnotation`` of its name, opened with the span and
+closed with it, so spans land in the profiler's trace on the
+profiler's clock, beside the device's events.  jax is never imported
+here: the annotation is opened only when jax is already imported.
+
 This module imports nothing from ``repro`` but the stdlib-only
 ``repro.cachedirs``, so every layer — ``sim.parallel`` included, which
 otherwise imports no repro siblings — can emit into it without a cycle.
@@ -34,6 +40,7 @@ from __future__ import annotations
 import itertools
 import json
 import os
+import sys
 import threading
 import time
 
@@ -81,17 +88,30 @@ def _jsonable(v):
     return repr(v)
 
 
+def _annotation(name: str):
+    """An opened ``jax.profiler.TraceAnnotation`` of ``name`` while a
+    profiler records, else None; jax is only looked up, never imported."""
+    profiler = getattr(sys.modules.get("jax"), "profiler", None)
+    if profiler is None or not profiler.TraceAnnotation.is_enabled():
+        return None
+    note = profiler.TraceAnnotation(name)
+    note.__enter__()
+    return note
+
+
 class Span:
     """A handle for an open span: settable attrs, explicit-parent anchor.
 
     Created via :meth:`Tracer.span`; use as a context manager.  The
     record is emitted at CLOSE time (one line per span), carrying
     ``t0`` (wall clock at open), ``dur_s`` (monotonic duration), the
-    span ``id``, its ``parent`` id and ``thread`` name.
+    span ``id``, its ``parent`` id and ``thread`` name.  While a JAX
+    profiler records, the span is also a profiler annotation of its
+    name from open to close.
     """
 
     __slots__ = ("tracer", "name", "id", "parent_id", "attrs",
-                 "_t0_wall", "_t0_mono", "_closed")
+                 "_t0_wall", "_t0_mono", "_closed", "_note")
 
     def __init__(self, tracer: "Tracer", name: str, span_id: int,
                  parent_id: int | None, attrs: dict):
@@ -103,6 +123,7 @@ class Span:
         self._t0_wall = time.time()
         self._t0_mono = time.perf_counter()
         self._closed = False
+        self._note = _annotation(name)
 
     def set(self, **attrs) -> "Span":
         """Attach/override attrs before the span closes."""
@@ -121,6 +142,8 @@ class Span:
             return
         self._closed = True
         dur = time.perf_counter() - self._t0_mono
+        if self._note is not None:
+            self._note.__exit__(None, None, None)
         self.tracer._pop(self)
         rec = {"kind": "span", "name": self.name, "id": self.id,
                "parent": self.parent_id,

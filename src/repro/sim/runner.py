@@ -367,7 +367,7 @@ def run_ladder(ladder: str, workloads=None, n: int = 150_000,
             return trace_gen.generate(w, n=n, seed=seed)
 
     with fill:
-        with jaxprof.maybe_profile(), recompile.count_compiles(
+        with recompile.count_compiles(
                 on_compile=lambda name: obs.event(
                     obs.names.EV_COMPILE, parent=fill, fn=name)), \
                 ThreadPoolExecutor(
