@@ -23,10 +23,18 @@ import jax.numpy as jnp
 from repro.core.assoc import (
     RRIP_MAX,
     Assoc,
+    get,
     insert_lru,
     lookup,
     make,
+    pick,
+    put,
+    put_row,
+    row,
+    set_assoc,
     set_index,
+    setv,
+    shape,
     srrip_age_and_pick,
     srrip_victim_tlb_aware,
     touch_lru,
@@ -59,31 +67,33 @@ def _l2_set(l2: "L2Cache", key: jax.Array, geom: L2Geom | None):
 def _way_ok(l2: "L2Cache", geom: L2Geom | None):
     if geom is None:
         return None
-    return jnp.arange(l2.tags.shape[1]) < geom.n_ways
+    return jnp.arange(l2.n_ways) < geom.n_ways
 
 
-class L2Cache(NamedTuple):
-    tags: jax.Array    # int32 [S, W]
-    valid: jax.Array   # bool  [S, W]
-    rrpv: jax.Array    # int32 [S, W]
-    btype: jax.Array   # int32 [S, W]
-    reuse: jax.Array   # int32 [S, W]
+@set_assoc("tags", "valid", "rrpv", "btype", "reuse")
+class L2Cache:
+    """The L2 cache: per-block arrays in set-major vector rows, as in
+    ``assoc.Assoc``, plus the reuse histograms and live counts."""
+
+    tags: jax.Array    # int32 [S/k, k*W]
+    valid: jax.Array   # bool  [S/k, k*W]
+    rrpv: jax.Array    # int32 [S/k, k*W]
+    btype: jax.Array   # int32 [S/k, k*W]
+    reuse: jax.Array   # int32 [S/k, k*W]
     hist_reuse_data: jax.Array  # int32 [REUSE_BUCKETS] — filled on eviction
     hist_reuse_tlb: jax.Array   # int32 [REUSE_BUCKETS]
     n_tlb4: jax.Array  # int32 scalar — live TLB blocks (4K)
     n_tlb2: jax.Array  # int32 scalar — live TLB blocks (2M)
     n_ntlb: jax.Array  # int32 scalar — live nested TLB blocks
-
-    @property
-    def n_sets(self) -> int:
-        return self.tags.shape[0]
+    n_ways: int        # static: ways per set
 
 
 def make_l2(n_sets: int, n_ways: int) -> L2Cache:
-    z = jnp.zeros((n_sets, n_ways), jnp.int32)
+    shp = shape(n_sets, n_ways)
+    z = jnp.zeros(shp, jnp.int32)
     return L2Cache(
         tags=z,
-        valid=jnp.zeros((n_sets, n_ways), jnp.bool_),
+        valid=jnp.zeros(shp, jnp.bool_),
         rrpv=z,
         btype=z,
         reuse=z,
@@ -92,7 +102,15 @@ def make_l2(n_sets: int, n_ways: int) -> L2Cache:
         n_tlb4=jnp.int32(0),
         n_tlb2=jnp.int32(0),
         n_ntlb=jnp.int32(0),
+        n_ways=n_ways,
     )
+
+
+def _l2_match(l2: L2Cache, s: jax.Array, key: jax.Array, btype):
+    """Ways of set ``s`` holding a valid ``key`` block of ``btype``."""
+    n = l2.n_ways
+    return (row(l2.valid, s, n) & (row(l2.tags, s, n) == key)
+            & (row(l2.btype, s, n) == btype))
 
 
 def l2_lookup(l2: L2Cache, key: jax.Array, btype,
@@ -100,7 +118,7 @@ def l2_lookup(l2: L2Cache, key: jax.Array, btype,
     s = _l2_set(l2, key, geom)
     # no way mask needed on probe: inserts never touch ways past the
     # view's limit, so those ways are never valid
-    hits = l2.valid[s] & (l2.tags[s] == key) & (l2.btype[s] == btype)
+    hits = _l2_match(l2, s, key, btype)
     return jnp.any(hits), jnp.argmax(hits), s
 
 
@@ -118,20 +136,24 @@ def l2_touch(
     Reuse counter increments (for Figs. 11/24).
     """
     en = jnp.asarray(enable)
-    is_tlbish = l2.btype[s, w] != BT_DATA
+    n = l2.n_ways
+    is_tlbish = get(l2.btype, s, w, n) != BT_DATA
     dec = jnp.where(is_tlbish & pressure & tlb_aware, 3, 1)
-    new_rrpv = jnp.maximum(l2.rrpv[s, w] - dec, 0)
+    rrpv = get(l2.rrpv, s, w, n)
+    new_rrpv = jnp.maximum(rrpv - dec, 0)
     return l2._replace(
-        rrpv=l2.rrpv.at[s, w].set(jnp.where(en, new_rrpv, l2.rrpv[s, w])),
-        reuse=l2.reuse.at[s, w].set(l2.reuse[s, w] + en.astype(jnp.int32)),
+        rrpv=put(l2.rrpv, s, w, n, jnp.where(en, new_rrpv, rrpv)),
+        reuse=put(l2.reuse, s, w, n,
+                  get(l2.reuse, s, w, n) + en.astype(jnp.int32)),
     )
 
 
 def _account_evict(l2: L2Cache, s, w, evicting) -> L2Cache:
     """Histogram + live-count bookkeeping for the block being replaced."""
-    bt = l2.btype[s, w]
-    was_valid = l2.valid[s, w] & evicting
-    bucket = jnp.minimum(l2.reuse[s, w], REUSE_BUCKETS - 1)
+    n = l2.n_ways
+    bt = get(l2.btype, s, w, n)
+    was_valid = get(l2.valid, s, w, n) & evicting
+    bucket = jnp.minimum(get(l2.reuse, s, w, n), REUSE_BUCKETS - 1)
     is_data = bt == BT_DATA
     one = jnp.int32(1)
     hist_d = l2.hist_reuse_data.at[bucket].add(
@@ -169,8 +191,10 @@ def l2_insert(
     btype = jnp.asarray(btype, jnp.int32)
     s = _l2_set(l2, key, geom)
     way_ok = _way_ok(l2, geom)
-    row_rrpv, row_valid = l2.rrpv[s], l2.valid[s]
-    row_is_tlb = l2.btype[s] != BT_DATA
+    n = l2.n_ways
+    row_rrpv, row_valid = row(l2.rrpv, s, n), row(l2.valid, s, n)
+    row_btype = row(l2.btype, s, n)
+    row_is_tlb = row_btype != BT_DATA
     if tlb_aware:
         aged, w = srrip_victim_tlb_aware(row_rrpv, row_valid, row_is_tlb,
                                          pressure, way_ok)
@@ -180,14 +204,17 @@ def l2_insert(
     l2 = _account_evict(l2, s, w, en)
     ins_is_tlbish = btype != BT_DATA
     ins_rrpv = jnp.where(ins_is_tlbish & pressure & tlb_aware, 0, RRIP_MAX - 1)
-    aged = aged.at[w].set(ins_rrpv)
+    aged = setv(aged, w, ins_rrpv)
     inc = en.astype(jnp.int32)
     return l2._replace(
-        tags=l2.tags.at[s, w].set(jnp.where(en, key, l2.tags[s, w])),
-        valid=l2.valid.at[s, w].set(l2.valid[s, w] | en),
-        rrpv=l2.rrpv.at[s].set(jnp.where(en, aged, l2.rrpv[s])),
-        btype=l2.btype.at[s, w].set(jnp.where(en, btype, l2.btype[s, w])),
-        reuse=l2.reuse.at[s, w].set(jnp.where(en, 0, l2.reuse[s, w])),
+        tags=put(l2.tags, s, w, n,
+                 jnp.where(en, key, get(l2.tags, s, w, n))),
+        valid=put(l2.valid, s, w, n, pick(row_valid, w) | en),
+        rrpv=put_row(l2.rrpv, s, n, jnp.where(en, aged, row_rrpv)),
+        btype=put(l2.btype, s, w, n,
+                  jnp.where(en, btype, pick(row_btype, w))),
+        reuse=put(l2.reuse, s, w, n,
+                  jnp.where(en, 0, get(l2.reuse, s, w, n))),
         n_tlb4=l2.n_tlb4 + jnp.where(btype == BT_TLB4, inc, 0),
         n_tlb2=l2.n_tlb2 + jnp.where(btype == BT_TLB2, inc, 0),
         n_ntlb=l2.n_ntlb + jnp.where(btype == BT_NTLB, inc, 0),
@@ -212,10 +239,7 @@ def l2_retag_to_tlb(
     """
     # check for an existing TLB block of this region+type (§5.2 step 2)
     s = _l2_set(l2, key, geom)
-    btype_arr = jnp.asarray(btype, jnp.int32)
-    exists = jnp.any(
-        l2.valid[s] & (l2.tags[s] == key) & (l2.btype[s] == btype_arr)
-    )
+    exists = jnp.any(_l2_match(l2, s, key, jnp.asarray(btype, jnp.int32)))
     return l2_insert(
         l2, key, btype, pressure, tlb_aware,
         jnp.asarray(enable) & ~exists, geom,
@@ -228,18 +252,21 @@ def l2_retag_to_tlb(
 def l3_access(l3: Assoc, key: jax.Array, enable):
     """Probe L3; fill on miss. Returns (l3, hit)."""
     en = jnp.asarray(enable)
+    n = l3.n_ways
     hit, w, s = lookup(l3, key)
     # hit: promote to RRPV 0
-    meta_hit = l3.meta.at[s, w].set(jnp.where(hit & en, 0, l3.meta[s, w]))
-    l3 = l3._replace(meta=meta_hit)
+    meta_row = row(l3.meta, s, n)
+    meta_row = setv(meta_row, w, jnp.where(hit & en, 0, pick(meta_row, w)))
     # miss: insert with SRRIP
-    aged, vw = srrip_age_and_pick(l3.meta[s], l3.valid[s])
+    valid_row = row(l3.valid, s, n)
+    aged, vw = srrip_age_and_pick(meta_row, valid_row)
     do_ins = en & ~hit
-    aged = aged.at[vw].set(RRIP_MAX - 1)
-    l3 = Assoc(
-        tags=l3.tags.at[s, vw].set(jnp.where(do_ins, key, l3.tags[s, vw])),
-        valid=l3.valid.at[s, vw].set(l3.valid[s, vw] | do_ins),
-        meta=l3.meta.at[s].set(jnp.where(do_ins, aged, l3.meta[s])),
+    aged = setv(aged, vw, RRIP_MAX - 1)
+    l3 = l3._replace(
+        tags=put(l3.tags, s, vw, n,
+                 jnp.where(do_ins, key, get(l3.tags, s, vw, n))),
+        valid=put(l3.valid, s, vw, n, pick(valid_row, vw) | do_ins),
+        meta=put_row(l3.meta, s, n, jnp.where(do_ins, aged, meta_row)),
     )
     return l3, hit
 

@@ -209,26 +209,30 @@ def make_step(cfg: SimConfig, stage_names=None, dyn: Dyn | None = None):
 
 def _final_hists(l2):
     """Fold still-resident blocks into the reuse histograms (blocks that
-    were never evicted would otherwise be invisible to Figs. 11/24)."""
+    were never evicted would otherwise be invisible to Figs. 11/24).
+    One lane's L2 arrays: every block counts once, whatever its set."""
     bucket = jnp.minimum(l2.reuse, 21)
     is_data = (l2.btype == BT_DATA) & l2.valid
     is_tlb = (l2.btype != BT_DATA) & l2.valid
     hd = l2.hist_reuse_data + jnp.zeros_like(l2.hist_reuse_data).at[
-        bucket.reshape(-1)].add(is_data.reshape(-1).astype(jnp.int32))
+        bucket].add(is_data.astype(jnp.int32))
     ht = l2.hist_reuse_tlb + jnp.zeros_like(l2.hist_reuse_tlb).at[
-        bucket.reshape(-1)].add(is_tlb.reshape(-1).astype(jnp.int32))
+        bucket].add(is_tlb.astype(jnp.int32))
     return hd, ht
 
 
-def _finalize(st: MMUState, batch_dims: int = 0):
+def _finalize(st: MMUState, batch_dims: int = 0, collect: bool = False):
     """Fold a finished state into the per-run output tuple (`batch_dims`
-    counts the leading workload/system axes on the state leaves)."""
+    counts the leading workload/system axes on the state leaves).  The
+    feature table and the 4K-page counters leave the device only where
+    the extras read them (`collect`): the counters alone are 4 MiB a
+    lane."""
     hists = _final_hists
     for _ in range(batch_dims):
         hists = jax.vmap(hists)
     hd, ht = hists(st.hier.l2)
     return (st.stats, st.hier.n_l2_access, st.hier.n_l2_miss, hd, ht,
-            st.feats, st.pc4,
+            st.feats if collect else None, st.pc4 if collect else None,
             (st.hier.n_l3_access, st.hier.n_l3_trans,
              st.hier.n_dramc_access, st.hier.n_dramc_hit))
 
@@ -282,13 +286,14 @@ def simulate(cfg: SimConfig, trace: dict, stage_names=None,
             body, make_state(cfg), trace, t,
             batch="map" if resolve_backend(backend) == "pallas"
             else "vmap")
-        outs = jax.jit(_finalize)(st)
+        outs = jax.jit(_finalize, static_argnames="collect")(
+            st, collect=cfg.collect)
     else:
         @jax.jit
         def run(tr):
             st = scan_accesses(step, make_state(cfg), tr,
                                backend=backend, block=block)
-            return _finalize(st)
+            return _finalize(st, collect=cfg.collect)
 
         outs = run(trace)
     stats, l2a, l2m, hd, ht, feats, pc4, shared = outs
@@ -316,7 +321,7 @@ def simulate_batch(cfg: SimConfig, traces: dict, stage_names=None,
         st = scan_accesses(
             lambda ss, acc: (jax.vmap(step)(ss, acc)[0], ()), st0, tr,
             backend=backend, block=block)
-        return _finalize(st, batch_dims=1)
+        return _finalize(st, batch_dims=1, collect=cfg.collect)
 
     stats, l2a, l2m, hd, ht, feats, pc4, shared = run(traces)
     stats = jax.tree.map(jax.device_get, stats)
@@ -340,6 +345,29 @@ def _step_sw(cfg: SimConfig, stage_names):
         return jax.vmap(per_sys)(ss, dyns), ()
 
     return step_sw
+
+
+def _lanes(d: Dyn, tr: dict, lanes: int):
+    """One lane axis of S x ``lanes`` lanes, system-major: each system's
+    ``Dyn`` scalars repeated per lane, the ``[T, lanes]`` traces tiled
+    once per system.  The scan backend runs its step vmapped once over
+    this axis: a nested vmap(system) o vmap(workload) hands back the flat
+    state arrays of every scatter transposed, and the loop carry then
+    pays a copy to restore its layout."""
+    s = jax.tree.leaves(d)[0].shape[0]
+    d_sw = jax.tree.map(lambda x: jnp.repeat(x, lanes, axis=0), d)
+    tr_sw = jax.tree.map(
+        lambda x: jnp.tile(x, (1, s) + (1,) * (x.ndim - 2)), tr)
+    return d_sw, tr_sw
+
+
+def _step_lanes(cfg: SimConfig, stage_names, d_sw: Dyn):
+    """Scan step over the single lane axis of ``_lanes``."""
+
+    def lane_step(ss, acc, dd):
+        return make_step(cfg, stage_names, dyn=dd)(ss, acc)[0]
+
+    return lambda ss, acc: (jax.vmap(lane_step)(ss, acc, d_sw), ())
 
 
 def _broadcast_state(cfg: SimConfig, lead: tuple[int, ...]) -> MMUState:
@@ -389,8 +417,7 @@ def make_systems_runner(cfg: SimConfig, plan, stage_names=None,
                 lambda x: x.reshape((x.shape[0], w_blk * c_blk)
                                     + x.shape[3:]), tr)
         lanes = w_blk if c_blk is None else w_blk * c_blk
-        with obs.scope(obs.names.SCOPE_INIT):
-            st0 = _broadcast_state(cfg, (lanes,))
+        s_blk = jax.tree.leaves(d)[0].shape[0]
 
         def unflatten(outs):
             if c_blk is None:
@@ -400,30 +427,28 @@ def make_systems_runner(cfg: SimConfig, plan, stage_names=None,
                                     + x.shape[2:]), outs)
 
         if backend == "scan":
-            def one_system(dd):
-                step = make_step(cfg, stage_names, dyn=dd)
-                st, _ = jax.lax.scan(
-                    lambda ss, acc: (jax.vmap(step)(ss, acc)[0], ()),
-                    st0, tr)
-                with obs.scope(obs.names.SCOPE_FINALIZE):
-                    return _finalize(st, batch_dims=1)
-
-            return unflatten(jax.vmap(one_system)(d))
+            d_sw, tr_sw = _lanes(d, tr, lanes)
+            with obs.scope(obs.names.SCOPE_INIT):
+                st0 = _broadcast_state(cfg, (s_blk * lanes,))
+            st, _ = jax.lax.scan(_step_lanes(cfg, stage_names, d_sw),
+                                 st0, tr_sw)
+            with obs.scope(obs.names.SCOPE_FINALIZE):
+                outs = _finalize(st, batch_dims=1, collect=cfg.collect)
+            return unflatten(jax.tree.map(
+                lambda x: x.reshape((s_blk, lanes) + x.shape[1:]), outs))
         # pallas: the system vmap moves inside the kernel's inner scan
         # (see _step_sw) so the pallas_call itself is never vmapped
-        s_blk = jax.tree.leaves(d)[0].shape[0]
         with obs.scope(obs.names.SCOPE_INIT):
             st0 = _broadcast_state(cfg, (s_blk, lanes))
         st = scan_accesses(_step_sw(cfg, stage_names), st0, tr,
                            backend=backend, consts=d, block=block)
         with obs.scope(obs.names.SCOPE_FINALIZE):
-            return unflatten(_finalize(st, batch_dims=2))
+            return unflatten(_finalize(st, batch_dims=2,
+                                       collect=cfg.collect))
 
     if t_shards <= 1:
         dispatch = parallel.shard_wrap(run_systems, plan)
     else:
-        sw = _step_sw(cfg, stage_names)
-
         def dispatch(dyns, traces):
             S = jax.tree.leaves(dyns)[0].shape[0]
             leaf = jax.tree.leaves(traces)[0]
@@ -434,17 +459,30 @@ def make_systems_runner(cfg: SimConfig, plan, stage_names=None,
                     lambda x: x.reshape((x.shape[0], W * c)
                                         + x.shape[3:]), traces)
             lanes = W if c is None else W * c
+            fin = jax.jit(_finalize,
+                          static_argnames=("batch_dims", "collect"))
 
-            def body(st, tr):
-                return scan_accesses(sw, st, tr, backend=backend,
-                                     consts=dyns, block=block)
+            if backend == "scan":  # the same single lane axis
+                d_sw, traces = _lanes(dyns, traces, lanes)
+                step = _step_lanes(cfg, stage_names, d_sw)
+                st, info = parallel.time_shard_scan(
+                    lambda st, tr: jax.lax.scan(step, st, tr)[0],
+                    _broadcast_state(cfg, (S * lanes,)), traces, t_shards)
+                outs = jax.tree.map(
+                    lambda x: x.reshape((S, lanes) + x.shape[1:]),
+                    fin(st, batch_dims=1, collect=cfg.collect))
+            else:
+                sw = _step_sw(cfg, stage_names)
 
-            st, info = parallel.time_shard_scan(
-                body, _broadcast_state(cfg, (S, lanes)), traces, t_shards,
-                batch="map" if backend == "pallas" else "vmap")
+                def body(st, tr):
+                    return scan_accesses(sw, st, tr, backend=backend,
+                                         consts=dyns, block=block)
+
+                st, info = parallel.time_shard_scan(
+                    body, _broadcast_state(cfg, (S, lanes)), traces,
+                    t_shards, batch="map")
+                outs = fin(st, batch_dims=2, collect=cfg.collect)
             run.last_time_shard_info = info
-            outs = jax.jit(_finalize, static_argnames="batch_dims")(
-                st, batch_dims=2)
             if c is not None:
                 outs = jax.tree.map(
                     lambda x: x.reshape(x.shape[:1] + (W, c)

@@ -33,7 +33,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import ptwcp
-from repro.core.assoc import Assoc, make
+from repro.core.assoc import Assoc, make, shape
 from repro.core.caches import Hier, L2Geom, Lat, make_hier
 from repro.core.page_table import PWCs, make_pwcs
 
@@ -286,12 +286,13 @@ class RevTable(NamedTuple):
     """
 
     tab: Assoc       # tags = lossy signature, meta = LRU stamp
-    vpn: jax.Array   # int32 [S, W] — enrolled key2 per way
+    vpn: jax.Array   # int32, stored like ``tab``'s arrays — enrolled
+    #                  key2 per way
 
 
 def make_rev(n_sets: int, n_ways: int) -> RevTable:
     return RevTable(tab=make(n_sets, n_ways),
-                    vpn=jnp.zeros((n_sets, n_ways), jnp.int32))
+                    vpn=jnp.zeros(shape(n_sets, n_ways), jnp.int32))
 
 
 class MMUState(NamedTuple):

@@ -3,7 +3,7 @@ from __future__ import annotations
 
 import jax.numpy as jnp
 
-from repro.core.assoc import insert_lru, lookup
+from repro.core.assoc import insert_lru, lookup, touch_lru
 from repro.core.stages.base import Stage, StageResult
 
 
@@ -15,10 +15,8 @@ class L1TLBStage(Stage):
         h4, w4, s4 = lookup(st.l1d4, req.vpn)
         h2, w2, s2 = lookup(st.l1d2, req.vpn2)
         hit1 = jnp.where(req.is2m, h2, h4)
-        l1d4 = st.l1d4._replace(meta=st.l1d4.meta.at[s4, w4].set(
-            jnp.where(h4 & ~req.is2m, req.now, st.l1d4.meta[s4, w4])))
-        l1d2 = st.l1d2._replace(meta=st.l1d2.meta.at[s2, w2].set(
-            jnp.where(h2 & req.is2m, req.now, st.l1d2.meta[s2, w2])))
+        l1d4 = touch_lru(st.l1d4, s4, w4, req.now, h4 & ~req.is2m)
+        l1d2 = touch_lru(st.l1d2, s2, w2, req.now, h2 & req.is2m)
         st = st._replace(l1d4=l1d4, l1d2=l1d2)
         return st, StageResult(hit=hit1, cycles=jnp.int32(cfg.l1tlb_lat),
                                info={})

@@ -11,7 +11,8 @@ from __future__ import annotations
 
 import jax.numpy as jnp
 
-from repro.core.assoc import insert_lru, insert_lru_dyn, lookup, lookup_dyn
+from repro.core.assoc import (insert_lru, insert_lru_dyn, lookup,
+                              lookup_dyn, touch_lru)
 from repro.core.stages.base import Stage, StageResult
 
 
@@ -29,9 +30,7 @@ class L2TLBStage(Stage):
                                      req.dyn.l2tlb_ways)
             lat = req.dyn.l2tlb_lat
         hit = need & ht
-        l2tlb = st.l2tlb._replace(meta=st.l2tlb.meta.at[stt, wt].set(
-            jnp.where(hit, req.now, st.l2tlb.meta[stt, wt])))
-        st = st._replace(l2tlb=l2tlb)
+        st = st._replace(l2tlb=touch_lru(st.l2tlb, stt, wt, req.now, hit))
         return st, StageResult(hit=hit, cycles=jnp.where(need, lat, 0),
                                info={})
 

@@ -3,7 +3,7 @@ from __future__ import annotations
 
 import jax.numpy as jnp
 
-from repro.core.assoc import insert_lru, lookup
+from repro.core.assoc import insert_lru, lookup, touch_lru
 from repro.core.stages.base import Stage, StageResult
 
 
@@ -18,9 +18,7 @@ class L3TLBStage(Stage):
         probe = need if len_ is None else need & len_
         h3, w3, s3 = lookup(st.l3tlb, req.key2)
         l3hit = probe & h3
-        l3tlb = st.l3tlb._replace(meta=st.l3tlb.meta.at[s3, w3].set(
-            jnp.where(l3hit, req.now, st.l3tlb.meta[s3, w3])))
-        st = st._replace(l3tlb=l3tlb)
+        st = st._replace(l3tlb=touch_lru(st.l3tlb, s3, w3, req.now, l3hit))
         # probe latency is paid by every access that reaches this level
         return st, StageResult(hit=l3hit, cycles=jnp.where(probe, lat, 0),
                                info={})

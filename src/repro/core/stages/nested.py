@@ -10,7 +10,7 @@ from __future__ import annotations
 import jax.numpy as jnp
 
 from repro.core import ptwcp
-from repro.core.assoc import insert_lru, lookup
+from repro.core.assoc import insert_lru, lookup, touch_lru
 from repro.core.caches import (BT_NTLB, access_pte, l2_lookup,
                                l2_retag_to_tlb, l2_touch)
 from repro.core.page_table import (PWC_LAT, PWCs, _level_lines_2m,
@@ -30,12 +30,7 @@ def nested_translate(cfg, st, gpn, pressure, l2_bypass, enable,
     lane, bit-exactly reproducing a plain-NP system when off."""
     en = jnp.asarray(enable)
     hit_n, w_n, s_n = lookup(st.ntlb, gpn)
-    ntlb = st.ntlb._replace(
-        meta=st.ntlb.meta.at[s_n, w_n].set(
-            jnp.where(en & hit_n, st.now, st.ntlb.meta[s_n, w_n])
-        )
-    )
-    st = st._replace(ntlb=ntlb)
+    st = st._replace(ntlb=touch_lru(st.ntlb, s_n, w_n, st.now, en & hit_n))
 
     miss = en & ~hit_n
     cycles = jnp.where(en, 1, 0)  # 1-cycle nested TLB

@@ -7,9 +7,7 @@ demand-walked entry and the L2 TLB's evicted entry.
 """
 from __future__ import annotations
 
-import jax.numpy as jnp
-
-from repro.core.assoc import insert_lru, lookup
+from repro.core.assoc import insert_lru, lookup, touch_lru
 from repro.core.caches import BT_TLB4, access_pte
 from repro.core.page_table import POM_BASE
 from repro.core.stages.base import Stage, StageResult, dramc_of, l2_geom_of
@@ -33,9 +31,7 @@ class POMStage(Stage):
         st = st._replace(hier=hier)
         hp, wp, sp = lookup(st.pom, req.key2)
         pomhit = probe & hp
-        pom = st.pom._replace(meta=st.pom.meta.at[sp, wp].set(
-            jnp.where(pomhit, req.now, st.pom.meta[sp, wp])))
-        st = st._replace(pom=pom)
+        st = st._replace(pom=touch_lru(st.pom, sp, wp, req.now, pomhit))
         return st, StageResult(hit=pomhit, cycles=pc_cyc, info={})
 
     def fill(self, cfg, st, req, out):

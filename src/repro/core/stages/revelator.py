@@ -41,7 +41,8 @@ from __future__ import annotations
 
 import jax.numpy as jnp
 
-from repro.core.assoc import Assoc, lru_victim, set_index
+from repro.core.assoc import (get, install, lru_victim, put, row,
+                              set_index, touch_lru)
 from repro.core.page_table import walk
 from repro.core.stages.base import (RevTable, Stage, StageResult,
                                     dramc_of, l2_geom_of,
@@ -59,14 +60,10 @@ def _rev_insert(rev: RevTable, sig, key2, now, enable) -> RevTable:
     tab = rev.tab
     s = set_index(sig, tab.n_sets)
     w = lru_victim(tab, s)
-    en = jnp.asarray(enable)
-    new_tab = Assoc(
-        tags=tab.tags.at[s, w].set(jnp.where(en, sig, tab.tags[s, w])),
-        valid=tab.valid.at[s, w].set(jnp.where(en, True, tab.valid[s, w])),
-        meta=tab.meta.at[s, w].set(jnp.where(en, now, tab.meta[s, w])),
-    )
-    return RevTable(tab=new_tab, vpn=rev.vpn.at[s, w].set(
-        jnp.where(en, key2, rev.vpn[s, w])))
+    new_tab, _, _ = install(tab, s, w, sig, now, enable)
+    n = tab.n_ways
+    return RevTable(tab=new_tab, vpn=put(
+        rev.vpn, s, w, n, jnp.where(enable, key2, get(rev.vpn, s, w, n))))
 
 
 class RevelatorStage(Stage):
@@ -78,23 +75,23 @@ class RevelatorStage(Stage):
         geom = l2_geom_of(req.dyn)
 
         sig = rev_sig(req.key2, cfg.rev_sig_bits)
-        tab = st.rev.tab
+        tab, n = st.rev.tab, st.rev.tab.n_ways
         s = set_index(sig, tab.n_sets)
-        row_hits = tab.valid[s] & (tab.tags[s] == sig)
+        row_hits = row(tab.valid, s, n) & (row(tab.tags, s, n) == sig)
         w = jnp.argmax(row_hits)
         sig_hit = probe & jnp.any(row_hits)
         # a lossy-signature hit whose enrolled page differs is the
         # misprediction: the speculative frame belonged to the alias
-        correct = sig_hit & (st.rev.vpn[s, w] == req.key2)
+        enrolled = get(st.rev.vpn, s, w, n)
+        correct = sig_hit & (enrolled == req.key2)
         mispred = sig_hit & ~correct
 
         # LRU touch + in-place repair (verification rewrites the aliased
         # entry with the walked translation; no-op on correct hits)
         rev = RevTable(
-            tab=tab._replace(meta=tab.meta.at[s, w].set(
-                jnp.where(sig_hit, req.now, tab.meta[s, w]))),
-            vpn=st.rev.vpn.at[s, w].set(
-                jnp.where(sig_hit, req.key2, st.rev.vpn[s, w])))
+            tab=touch_lru(tab, s, w, req.now, sig_hit),
+            vpn=put(st.rev.vpn, s, w, n,
+                    jnp.where(sig_hit, req.key2, enrolled)))
         st = st._replace(rev=rev)
 
         # verification walk — real PT/cache traffic, off the critical

@@ -28,7 +28,7 @@ from __future__ import annotations
 import jax.numpy as jnp
 
 from repro.core.assoc import (insert_lru, insert_lru_dyn, lookup,
-                              lookup_dyn)
+                              lookup_dyn, touch_lru)
 from repro.core.caches import BT_TLB4, access_pte
 from repro.core.page_table import RESTSEG2_BASE, RESTSEG4_BASE
 from repro.core.stages.base import (Stage, StageResult, dramc_of,
@@ -67,11 +67,9 @@ class RestSegStage(Stage):
         hit4 = probe & ~req.is2m & h4
         hit2 = probe & req.is2m & h2
         # LRU touch keeps conflict demotions picking the coldest resident
-        rs4 = st.restseg4._replace(meta=st.restseg4.meta.at[i4, w4].set(
-            jnp.where(hit4, req.now, st.restseg4.meta[i4, w4])))
-        rs2 = st.restseg2._replace(meta=st.restseg2.meta.at[i2, w2].set(
-            jnp.where(hit2, req.now, st.restseg2.meta[i2, w2])))
-        st = st._replace(restseg4=rs4, restseg2=rs2)
+        st = st._replace(
+            restseg4=touch_lru(st.restseg4, i4, w4, req.now, hit4),
+            restseg2=touch_lru(st.restseg2, i2, w2, req.now, hit2))
 
         rhit = hit4 | hit2
         return st, StageResult(hit=rhit, cycles=cyc,

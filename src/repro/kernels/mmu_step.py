@@ -1,10 +1,8 @@
 """Pallas-fused blocked access scan for the MMU translation pipeline.
 
 ``jax.lax.scan`` threads the FULL ``MMUState`` carry through every
-access: each step's gather/scatter-heavy assoc probes force XLA to
-materialize the whole carry pytree per iteration, so the hot sweep loop
-is dominated by carry traffic, not by translation math.  This kernel
-restructures the scan into a grid of trace *blocks*:
+access in device memory.  This kernel restructures the scan into a grid
+of trace *blocks* so that the carry stays resident instead:
 
   - the state pytree lives in kernel-resident buffers (VMEM on TPU) with
     a constant ``index_map``, so it persists ACROSS grid steps and is

@@ -58,6 +58,7 @@ def test_assoc_masked_view_equals_small_static():
             assert bool(ev_v_b) == bool(ev_v_s)
             if bool(ev_v_s):
                 assert int(ev_t_b) == int(ev_t_s)
+    big, small = assoc.rows(big), assoc.rows(small)
     assert np.array_equal(np.asarray(big.tags)[:SETS, :WAYS],
                           np.asarray(small.tags))
     assert np.array_equal(np.asarray(big.valid)[:SETS, :WAYS],
@@ -71,6 +72,7 @@ def test_assoc_masked_view_equals_small_static():
 
 
 def _assert_l2_view_equal(big, small, sets, ways):
+    big, small = assoc.rows(big), assoc.rows(small)
     for field in ("tags", "valid", "rrpv", "btype", "reuse"):
         a = np.asarray(getattr(big, field))[:sets, :ways]
         b = np.asarray(getattr(small, field))
@@ -188,8 +190,9 @@ def test_restseg_masked_view_equals_small_static():
                                                   ways, mig)
             small, _, conf_s = assoc.insert_lru(small, vpn, now, mig)
             assert bool(conf_b) == bool(conf_s), t
-        occupancy = np.asarray(big.valid).sum(axis=1)
+        occupancy = np.asarray(assoc.rows(big).valid).sum(axis=1)
         assert occupancy.max() <= WAYS, t
+    big, small = assoc.rows(big), assoc.rows(small)
     assert np.array_equal(np.asarray(big.tags)[:, :WAYS],
                           np.asarray(small.tags))
     assert not np.asarray(big.valid)[:, WAYS:].any()
@@ -235,7 +238,7 @@ def test_restseg_migration_invariants():
     ways_alloc = dataclasses.replace(cfg, restseg_ways=8)
     st = _simulate_final_state(ways_alloc, trace, dyn=dyn_of(cfg))
     for rs in (st.restseg4, st.restseg2):
-        valid = np.asarray(rs.valid)
+        valid = np.asarray(assoc.rows(rs).valid)
         assert valid.sum(axis=1).max() <= cfg.restseg_ways
         assert not valid[:, cfg.restseg_ways:].any()
     assert np.asarray(st.restseg4.valid).any()  # migrations landed

@@ -300,6 +300,29 @@ def test_time_sharded_systems_requires_1x1_plan(short_traces):
         mmu.make_systems_runner(base, plan, time_shards=2)
 
 
+@pytest.mark.parametrize("backend", ["scan", "pallas"])
+def test_time_sharded_systems_match_serial_per_lane(backend, short_traces):
+    """Two systems x two distinct workloads, time-sharded: each (system,
+    workload) lane ends bit-identical to the un-sharded scan dispatch,
+    on the scan backend's single S x W lane axis and on the pallas
+    backend's nested one."""
+    tr, _ = short_traces
+    traces = {k: jnp.stack([v, v[::-1]], axis=1) for k, v in tr.items()}
+    cfgs = [GOLDEN_CFG, dataclasses.replace(GOLDEN_CFG, victima=True)]
+    base, dyns = systems.dyn_base_config(cfgs), _stack_dyns(cfgs)
+    per_ref, _ = mmu.simulate_systems(base, dyns, traces)
+    per_t, _ = mmu.simulate_systems(base, dyns, traces, backend=backend,
+                                    time_shards=2)
+    for si in range(2):
+        for wi in range(2):
+            _assert_same_stats(per_ref[si][wi], per_t[si][wi], (si, wi))
+    # the lanes differ, so a lane read from the wrong place would show
+    for a, b in ((per_ref[0][0], per_ref[0][1]),
+                 (per_ref[0][0], per_ref[1][0])):
+        assert any(not np.array_equal(np.asarray(x), np.asarray(y))
+                   for x, y in zip(a, b))
+
+
 # --------------------------------------------- runner/perf-record plumbing
 
 
