@@ -3,9 +3,10 @@
 Set-up builds ONE ladder runner, ``mmu.make_systems_runner(cfg, plan)``
 with the ladder's ``ladder_base_config``, ``ladder_dyn`` and
 ``plan_mesh``, as ``runner.run_ladder`` builds it, and warms its single
-``[S, W, seg_accesses]`` shape with one dispatch.  The window then calls
-``run_fn(dyns, traces)`` on fresh segments until ``seconds`` have
-passed; a dispatch that starts before the deadline finishes.  A
+``[S, W, seg_accesses]`` shape (``[S, W, C, seg_accesses]`` for a
+traffic file that declares C ``cores``) with one dispatch.  The window
+then calls ``run_fn(dyns, traces)`` on fresh segments until ``seconds``
+have passed; a dispatch that starts before the deadline finishes.  A
 producer thread stacks the next dispatch's segments while the current
 one runs.  Every segment starts from cold MMU state: the program has no
 entry that carries state from one dispatch to the next.
@@ -61,9 +62,10 @@ class CompileWatch:
         jax.monitoring.unregister_event_duration_listener(self)
 
 
-def check_config(cfg_file: dict) -> tuple:
+def check_config(cfg_file: dict, cores: int = 1) -> tuple:
     """The ladder the file names, checked member by member against the
-    program's registry; returns (members, program base cfg, dyns)."""
+    program's registry and its core count against the traffic's
+    ``cores``; returns (members, program base cfg, dyns)."""
     from bench.reference import lane
     from repro.sim import systems
 
@@ -81,6 +83,10 @@ def check_config(cfg_file: dict) -> tuple:
         if diff:
             raise ValueError(f"member {m!r} runs with {diff} unlike its "
                              f"configuration file")
+    if systems.mix_cores(members) != cores:
+        raise ValueError(f"ladder {ladder!r} runs "
+                         f"{systems.mix_cores(members)} cores, the traffic "
+                         f"{cores}")
     return (members, systems.ladder_base_config(ladder),
             systems.ladder_dyn(members))
 
@@ -96,10 +102,8 @@ class Cell:
         self.scratch = scratch
         self.seg = int(traffic["seg_accesses"])
         self.width = int(traffic["width"])
-        self.specs = gen.workload_specs(traffic)
-        self.streams = [gen.Stream(s, seed, self.seg,
-                                   int(traffic["block_accesses"]))
-                        for s in self.specs]
+        self.cores = int(traffic.get("cores", 1))
+        self.sources = gen.sources(traffic, seed)
         self.dispatched: list[dict] = []   # numpy traces of each dispatch
         self.results: list[tuple] = []     # (per, extras) of each dispatch
         self.times: list[tuple] = []       # (start, end) of each dispatch
@@ -107,8 +111,9 @@ class Cell:
 
     # ------------------------------------------------------------ traffic
     def segments(self, k: int) -> dict:
-        cols = gen.dispatch_plan(len(self.specs), self.width, k)
-        return gen.stack([self.streams[w].segment(j) for w, j in cols])
+        cols = gen.dispatch_plan(len(self.sources), self.width, k)
+        return gen.stack([gen.source_segment(self.sources[w], j)
+                          for w, j in cols])
 
     def _producer(self, q: queue.Queue, stop: threading.Event, k0: int):
         """Stack dispatch ``k0``, ``k0+1``, ... into ``q`` until stopped;
@@ -137,16 +142,19 @@ class Cell:
         from repro.core import mmu
         from repro.sim import parallel
 
-        self.members, cfg, self.dyns = check_config(self.cfg_file)
+        self.members, cfg, self.dyns = check_config(self.cfg_file,
+                                                    self.cores)
         mesh = self.traffic.get("mesh")
         self.plan = parallel.plan_mesh(
             len(self.members), self.width, n_devices=self.n_devices,
-            force=tuple(mesh) if mesh else None)
+            force=tuple(mesh) if mesh else None, n_cores=self.cores)
         self.run_fn = mmu.make_systems_runner(cfg, self.plan)
         self.lanes_per_chip = ((self.plan.pad_systems // self.plan.sys_dim)
-                               * (self.width // self.plan.wl_dim))
+                               * (self.width // self.plan.wl_dim)
+                               * (self.cores // self.plan.core_dim))
         log(f"ladder {self.cfg_file['ladder']}: {len(self.members)} systems "
-            f"x {self.width} workloads, mesh {self.plan.describe()}, "
+            f"x {self.width} workloads x {self.cores} cores, mesh "
+            f"{self.plan.describe()}, "
             f"{self.lanes_per_chip} lanes per chip, {self.seg} accesses "
             f"per segment")
         t0 = time.perf_counter()
@@ -208,7 +216,8 @@ class Cell:
 
     @property
     def real_lanes(self) -> int:
-        return len(self.members) * self.width
+        """Lanes of a dispatch; a core of a mix is a lane of its own."""
+        return len(self.members) * self.width * self.cores
 
     def accesses(self) -> int:
         return self.real_lanes * self.seg * len(self.times)

@@ -1,7 +1,8 @@
 """Whether the timed dispatches were right: a sample of their lanes,
 drawn from the seed, against the per-lane reference.
 
-Each sampled lane-segment (one system of one dispatch column) is run
+Each sampled lane-segment (one system of one dispatch column, and of
+one core where the traffic runs multiprogrammed mixes) is run
 again by ``bench.reference`` on the host CPU, alone, on its member's own
 static config (its own table sizes and stage composition: no ladder
 allocation, no per-lane sizing scalars, no stage gates) and the very
@@ -26,16 +27,19 @@ WORKERS = max(1, min(12, (os.cpu_count() or 2) - 1))
 
 
 def sample(n_members: int, n_dispatches: int, width: int, per_member: int,
-           seed: int) -> list[tuple[int, int, int]]:
-    """``per_member`` distinct (dispatch, column) cells for every member,
-    drawn from the seed: [(member, dispatch, column), ...]."""
+           seed: int, cores: int = 1) -> list[tuple[int, ...]]:
+    """``per_member`` distinct (dispatch, column[, core]) cells for every
+    member, drawn from the seed: [(member, dispatch, column[, core]),
+    ...]; the core only where ``cores`` > 1."""
     rng = np.random.default_rng([int(seed), 0xC4EC])
-    cells = n_dispatches * width
+    cells = n_dispatches * width * cores
     out = []
     for s in range(n_members):
         for c in rng.choice(cells, size=min(per_member, cells),
                             replace=False):
-            out.append((s, int(c) // width, int(c) % width))
+            k, rest = divmod(int(c), width * cores)
+            lane = divmod(rest, cores) if cores > 1 else (rest,)
+            out.append((s, k, *lane))
     return out
 
 
@@ -119,31 +123,49 @@ class Reference:
         return out
 
 
-def column(traces: dict, w: int) -> dict:
-    return {k: np.ascontiguousarray(v[:, w]) for k, v in traces.items()}
+def column(traces: dict, *lane: int) -> dict:
+    """Lane ``(column[, core])`` of a dispatch's [T, W(, C)] traces."""
+    return {k: np.ascontiguousarray(v[(slice(None), *lane)])
+            for k, v in traces.items()}
+
+
+def at(rows, lane):
+    """``rows[w]``, or ``rows[w][c]``: one lane of a member's outputs."""
+    for i in lane:
+        rows = rows[i]
+    return rows
+
+
+def _leaves(rows):
+    """Every Stats of a member's row, through the per-core lists."""
+    for x in rows:
+        if isinstance(x, list):
+            yield from _leaves(x)
+        else:
+            yield x
 
 
 def run_check(cell, ref: Reference, per_member: int, log=print) -> dict:
     """Compare the cell's window against the reference; returns the
     compared numbers ``{name: value}`` (limits in ``LIMITS``)."""
-    short = 0
-    for per, _ in cell.results:
-        for row in per:
-            for st in row:
-                short += int(st.n_access) != cell.seg
+    short = sum(int(st.n_access) != cell.seg
+                for per, _ in cell.results for st in _leaves(per))
     picks = sample(len(cell.members), len(cell.results), cell.width,
-                   per_member, cell.seed)
-    jobs = [(s, column(cell.dispatched[k], w)) for s, k, w in picks]
+                   per_member, cell.seed, cell.cores)
+    jobs = [(s, column(cell.dispatched[k], *lane))
+            for s, k, *lane in picks]
     want = ref.lanes(jobs)
     bad = 0
     active: dict[str, dict[str, int]] = {}
-    for (s, k, w), (ref_out, act) in zip(picks, want):
+    for (s, k, *lane), (ref_out, act) in zip(picks, want):
         per, extras = cell.results[k]
-        d = diff(program_lane(per[s][w], extras[s][w]), ref_out)
+        d = diff(program_lane(at(per[s], lane), at(extras[s], lane)),
+                 ref_out)
         if d:
             bad += 1
             if bad <= 5:
-                log(f"lane {cell.members[s]} / dispatch {k} column {w}: "
+                log(f"lane {cell.members[s]} / dispatch {k} column "
+                    f"{lane[0]}{' core %d' % lane[1] if lane[1:] else ''}: "
                     f"differs from the reference in {d}")
         tot = active.setdefault(cell.members[s], dict.fromkeys(act, 0))
         for name, n in act.items():

@@ -7,9 +7,9 @@ For each seed it draws the lanes a run of ``--dispatches`` window
 dispatches would sample, runs them through the float32 reference and
 through the bfloat16 one, and prints how many of them differ: the
 check's ``lanes_mismatched`` as the control reads it.  ``--dispatches``
-is what a window of ``run_seconds`` makes for the cell (at 20 s: 2 for
-``native28.all11``, 7 for ``virt5.all11``).  Runs on the host CPU; it
-needs no chip.
+is what a window of ``run_seconds`` makes for the cell (at 20 s on one
+v5e: about 10 for ``native28.all11`` and 34 for ``virt5.all11``).  Runs
+on the host CPU; it needs no chip.
 """
 import argparse
 import json
@@ -20,7 +20,8 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if __name__ == "__main__":
     sys.path[:0] = [ROOT, os.path.join(ROOT, "src")]
 
-from bench import check, gen, spec  # noqa: E402
+from bench import cell as cellmod  # noqa: E402
+from bench import check, spec  # noqa: E402
 
 
 def control_lanes(cfg_file: dict, traffic: dict, seed: int,
@@ -28,15 +29,13 @@ def control_lanes(cfg_file: dict, traffic: dict, seed: int,
     """Mismatched lanes of the bfloat16 reference against the float32
     one, over the sample a run of ``dispatches`` would check."""
     members = list(cfg_file["members"])
-    seg, width = int(traffic["seg_accesses"]), int(traffic["width"])
-    streams = [gen.Stream(s, seed, seg, int(traffic["block_accesses"]))
-               for s in gen.workload_specs(traffic)]
+    c = cellmod.Cell(cfg_file, traffic, seed, 1, "")
     per = int(cfg_file["check_per_member"])
-    picks = check.sample(len(members), dispatches, width, per, seed)
-    jobs = []
-    for s, k, w in picks:
-        wl, j = gen.dispatch_plan(len(streams), width, k + 1)[w]
-        jobs.append((s, streams[wl].segment(j)))
+    picks = check.sample(len(members), dispatches, c.width, per, seed,
+                         c.cores)
+    # the window's dispatch k is the cell's k + 1 (0 warms the shape)
+    jobs = [(s, check.column(c.segments(k + 1), *lane))
+            for s, k, *lane in picks]
     want = check.Reference(cfg_file, members).lanes(jobs)
     got = check.Reference(cfg_file, members, acc="bfloat16").lanes(jobs)
     bad = [check.diff(g, w) for (g, _), (w, _) in zip(got, want)]

@@ -10,6 +10,10 @@ cannot move the yardstick.  What differs is how it is cut:
   ``block_accesses``; block ``b`` is drawn from its own generator seeded
   by ``(seed, b, crc32(name))``, so the stream extends without bound and
   any segment can be regenerated on its own;
+- a traffic file with ``"cores": C`` runs multiprogrammed ``mixes`` of C
+  workloads: core ``c`` of mix ``m`` is a stream of its own, seeded by
+  ``(seed, b, crc32(name), m, c)``, so two cores on one workload never
+  alias;
 - segment ``j`` of a workload is accesses ``[j*seg, (j+1)*seg)`` of its
   stream, so consecutive segments tile the stream;
 - a block is drawn ``4 * reref_window`` accesses longer and that head
@@ -55,16 +59,20 @@ def _zipf_pages(rng, n: int, n_pages: int, a: float) -> np.ndarray:
     return pr.astype(np.int64)
 
 
-def block(spec: WorkloadSpec, n: int, seed: int, index: int) -> dict:
-    """Block ``index`` of ``spec``'s stream: {vpn, is2m, line}, [n]."""
+def block(spec: WorkloadSpec, n: int, seed: int, index: int,
+          lane: tuple[int, ...] = ()) -> dict:
+    """Block ``index`` of ``spec``'s stream: {vpn, is2m, line}, [n].
+    ``lane`` is ``(mix, core)`` for a core of a multiprogrammed mix."""
     lead = 4 * spec.reref_window if spec.reref_frac > 0 else 0
-    out = _draw(spec, n + lead, seed, index)
+    out = _draw(spec, n + lead, seed, index, lane)
     return {k: v[lead:] for k, v in out.items()}
 
 
-def _draw(spec: WorkloadSpec, n: int, seed: int, index: int) -> dict:
+def _draw(spec: WorkloadSpec, n: int, seed: int, index: int,
+          lane: tuple[int, ...]) -> dict:
     rng = np.random.default_rng(
-        [int(seed), int(index), zlib.crc32(spec.name.encode())])
+        [int(seed), int(index), zlib.crc32(spec.name.encode()),
+         *map(int, lane)])
     n_pages = min(int(spec.footprint_gb * GB / PAGE4), MAX_PAGES4)
     n4 = int(n_pages * (1.0 - spec.thp_frac))
     n4 = max(512, n4 - (n4 % 512))
@@ -114,12 +122,13 @@ class Stream:
     """One workload's access stream for one seed, read segment by segment."""
 
     def __init__(self, spec: WorkloadSpec, seed: int, seg: int,
-                 block_accesses: int):
+                 block_accesses: int, lane: tuple[int, ...] = ()):
         if seg <= 0 or block_accesses % seg:
             raise ValueError(f"segment length {seg} must divide the block "
                              f"length {block_accesses}")
         self.spec, self.seed, self.seg = spec, seed, seg
         self.block_accesses = block_accesses
+        self.lane = tuple(lane)
         self._blocks: dict[int, dict] = {}
 
     def segment(self, j: int) -> dict:
@@ -127,7 +136,7 @@ class Stream:
         b, off = divmod(j * self.seg, self.block_accesses)
         if b not in self._blocks:
             self._blocks = {b: block(self.spec, self.block_accesses,
-                                     self.seed, b)}
+                                     self.seed, b, self.lane)}
         blk = self._blocks[b]
         out = {k: v[off:off + self.seg] for k, v in blk.items()}
         out["ipa"] = np.full((self.seg,), self.spec.ipa, np.float32)
@@ -138,13 +147,45 @@ def workload_specs(traffic: dict) -> list[WorkloadSpec]:
     return [WorkloadSpec(**w) for w in traffic["workloads"]]
 
 
+def sources(traffic: dict, seed: int) -> list[list[Stream]]:
+    """The streams of each column source: one per workload, or, where
+    the file declares ``cores``, C per mix (core ``c`` of mix ``m``
+    runs ``mixes[m][c]`` on its own stream)."""
+    seg, blk = int(traffic["seg_accesses"]), int(traffic["block_accesses"])
+    specs = workload_specs(traffic)
+    cores = int(traffic.get("cores", 1))
+    if cores == 1:
+        if "mixes" in traffic:
+            raise ValueError("mixes need \"cores\" above 1")
+        return [[Stream(s, seed, seg, blk)] for s in specs]
+    by_name = {s.name: s for s in specs}
+    mixes = traffic.get("mixes")
+    if not mixes or any(len(mix) != cores for mix in mixes):
+        raise ValueError(f"every mix must list {cores} workloads")
+    return [[Stream(by_name[n], seed, seg, blk, (m, c))
+             for c, n in enumerate(mix)] for m, mix in enumerate(mixes)]
+
+
+def source_segment(source: list[Stream], j: int) -> dict:
+    """Segment ``j`` of one column source: [T] leaves for a workload,
+    [T, C] leaves and a ``core`` leaf (int32, value c) for a mix."""
+    if len(source) == 1:
+        return source[0].segment(j)
+    out = stack([s.segment(j) for s in source])
+    out["core"] = np.broadcast_to(
+        np.arange(len(source), dtype=np.int32), out["vpn"].shape)
+    return out
+
+
 def dispatch_plan(n_workloads: int, width: int, k: int) -> list[tuple[int, int]]:
-    """(workload index, segment index) of each column of dispatch ``k``.
+    """(workload or mix index, segment index) of each column of
+    dispatch ``k``.
 
     Dispatch ``k`` takes workloads ``width*k .. width*k + width-1``
-    (mod the workload count), as a ladder fill chunks its workload list;
-    each workload's segment index counts the earlier dispatches it was
-    in, so every column reads the next unread segment of its stream.
+    (mod the workload count), as a ladder fill chunks its workload (or
+    mix) list; each workload's segment index counts the earlier
+    dispatches it was in, so every column reads the next unread segment
+    of its stream.
     """
     cols = []
     for c in range(width):
@@ -155,6 +196,7 @@ def dispatch_plan(n_workloads: int, width: int, k: int) -> list[tuple[int, int]]
 
 
 def stack(segments: list[dict]) -> dict[str, np.ndarray]:
-    """Columns of one dispatch, stacked to [T, W] leaves."""
+    """Columns of one dispatch, stacked to [T, W] leaves ([T, W, C] for
+    mixes)."""
     return {k: np.stack([s[k] for s in segments], axis=1)
             for k in segments[0]}

@@ -209,3 +209,29 @@ def test_execute_prints_a_whole_result(tiny, monkeypatch):
                                    "setup_s"}
     assert out["check"]["lanes_mismatched"] == {"value": 0, "limit": 0}
     json.dumps(out)
+
+
+def test_single_core_reference_as_recorded(tiny):
+    """The reference's outputs on this cell's lanes, every field, equal
+    those recorded before the reference learned the core axis."""
+    import os
+    import zlib
+
+    import jax
+
+    from bench.reference import lane
+
+    _, cfg, traffic = tiny
+    with open(os.path.join(os.path.dirname(__file__), "fixtures",
+                           "single_core_outputs.json"),
+              encoding="utf-8") as f:
+        pins = json.load(f)
+    assert pins["members"] == MEMBERS
+    c = cellmod.Cell(cfg, traffic, 2**31 + 11, 1, "")
+    runs = {m: lane.lane_fn(lane.sim_config(ov)) for m, ov in MEMBERS.items()}
+    for want in pins["reference"]:
+        tr = check.column(c.segments(want["dispatch"]), want["column"])
+        out = lane.outputs(jax.device_get(runs[want["member"]](tr)))
+        got = {k: zlib.crc32(np.ascontiguousarray(v).tobytes())
+               for k, v in out.items()}
+        assert got == want["crc32"], want
